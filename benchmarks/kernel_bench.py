@@ -49,8 +49,8 @@ def run(smoke: bool = False) -> list:
     # paged attention (decode class)
     P_, psz, pps = (32, 8, 8) if smoke else (128, 16, 16)
     q2 = jax.random.normal(ks[0], (8, H, D), jnp.float32).astype(jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (P_, psz, Kv, D), jnp.float32).astype(jnp.bfloat16)
-    vp = jax.random.normal(ks[2], (P_, psz, Kv, D), jnp.float32).astype(jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (P_, Kv, psz, D), jnp.float32).astype(jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (P_, Kv, psz, D), jnp.float32).astype(jnp.bfloat16)
     pt = jax.random.randint(key, (8, pps), 0, P_)
     lens = jnp.full((8,), pps * psz, jnp.int32)
     f2 = jax.jit(lambda *a: ref.paged_attention_ref(*a))
@@ -96,12 +96,12 @@ def run(smoke: bool = False) -> list:
     P2, psz2, pps2 = 1024, 16, 512         # ctx = 8192 tokens
     q8 = jax.random.normal(ks[0], (Bq2, 1, H2, D2),
                            jnp.float32).astype(jnp.bfloat16)
-    kb = jax.random.normal(ks[1], (P2, psz2, Kv2, D2),
+    kb = jax.random.normal(ks[1], (P2, Kv2, psz2, D2),
                            jnp.float32).astype(jnp.bfloat16)
-    vb = jax.random.normal(ks[2], (P2, psz2, Kv2, D2),
+    vb = jax.random.normal(ks[2], (P2, Kv2, psz2, D2),
                            jnp.float32).astype(jnp.bfloat16)
-    k8 = jax.random.randint(ks[1], (P2, psz2, Kv2, D2), -127, 128, jnp.int8)
-    v8 = jax.random.randint(ks[2], (P2, psz2, Kv2, D2), -127, 128, jnp.int8)
+    k8 = jax.random.randint(ks[1], (P2, Kv2, psz2, D2), -127, 128, jnp.int8)
+    v8 = jax.random.randint(ks[2], (P2, Kv2, psz2, D2), -127, 128, jnp.int8)
     s8 = (jax.random.uniform(ks[0], (P2, psz2, Kv2)) * 0.02
           ).astype(jnp.bfloat16)
     pt8 = jax.random.randint(key, (Bq2, pps2), 0, P2)

@@ -54,6 +54,8 @@ def main() -> None:
                          "so they can't clobber a full-registry record)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_repo_compile_cache
+    use_repo_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     report_rows = []

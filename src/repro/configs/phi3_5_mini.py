@@ -1,6 +1,8 @@
 """phi-3.5-mini (3.8B) — the paper's Table 1 model #2. [arXiv:2404.14219]
 
-32L, d_model=3072, 32 heads (GQA kv=8 in 3.5-mini), d_ff=8192, vocab 32064.
+32L, d_model=3072, 32 heads with 32 kv heads (multi-head attention:
+``num_key_value_heads: 32`` in microsoft/Phi-3.5-mini-instruct), d_ff=8192,
+vocab 32064 — 3.82B parameters.
 """
 from repro.configs.base import LayerSpec, ModelConfig, pattern_from_rule
 
@@ -9,7 +11,7 @@ CONFIG = ModelConfig(
     n_layers=32,
     d_model=3072,
     n_heads=32,
-    n_kv_heads=8,
+    n_kv_heads=32,
     d_ff=8192,
     vocab_size=32064,
     layer_pattern=pattern_from_rule(32, lambda i: LayerSpec("attn", "dense")),

@@ -64,6 +64,7 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Union
 
+import jax
 import numpy as np
 
 from repro.core import api
@@ -268,6 +269,11 @@ class MLCEngine:
         self._thread: Optional[threading.Thread] = None
         self._shutdown = False
         self._t_activity = time.time()    # last time any step made progress
+        #: the ``jax.default_device`` in force at construction (a router
+        #: replica's chip; None: JAX's default).  Models load under it,
+        #: and the loop thread re-enters it, so every step and late
+        #: allocation stays on that device.
+        self._device = jax.config.jax_default_device
 
     # -- model management ----------------------------------------------
     def load_model(self, name: str, cfg, *, params=None, tokenizer=None,
@@ -318,8 +324,9 @@ class MLCEngine:
         ``max_cached_bytes``
             The same cap expressed in BYTES of KV payload — divided by
             this model's per-page byte cost, computed from the actual
-            pool dtypes (``2 * n_layers * page_size * n_kv_heads *
-            (head_dim * kv_elem_bytes + scale_bytes)``: bf16 vectors by
+            pool dtypes and the pools' 128-lane padded head width
+            (``2 * n_layers * page_size * n_kv_heads * (head_dim_pad *
+            kv_elem_bytes + scale_bytes)``: bf16 vectors by
             default; int8 vectors plus a bf16 scale per (token,
             kv-head) under ``kv_dtype="int8"``) — so one byte budget
             can govern several loaded models of different shapes and
@@ -584,26 +591,27 @@ class MLCEngine:
 
     def _loop(self):
         try:
-            idle_since = time.time()
-            while not self._shutdown:
-                busy = self.step()
-                if busy:
-                    idle_since = time.time()
-                else:
-                    if time.time() - idle_since > 5.0:
-                        # retire — but re-check for work under the lock
-                        # so a request enqueued this instant is not
-                        # stranded
-                        with self._lock:
-                            if any(lm.scheduler.waiting
-                                   or lm.scheduler.running
-                                   for lm in self.models.values()):
-                                idle_since = time.time()
-                                continue
-                            self._thread = None
-                            return
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+            with jax.default_device(self._device):
+                idle_since = time.time()
+                while not self._shutdown:
+                    busy = self.step()
+                    if busy:
+                        idle_since = time.time()
+                    else:
+                        if time.time() - idle_since > 5.0:
+                            # retire — but re-check for work under the lock
+                            # so a request enqueued this instant is not
+                            # stranded
+                            with self._lock:
+                                if any(lm.scheduler.waiting
+                                       or lm.scheduler.running
+                                       for lm in self.models.values()):
+                                    idle_since = time.time()
+                                    continue
+                                self._thread = None
+                                return
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
         except BaseException as e:
             # step() already contains the per-batch failure handling; an
             # exception escaping to here means the loop itself is broken.

@@ -1,7 +1,11 @@
 """PagedModelRunner: ragged fused steps through the paged KV cache.
 
-The TPU-native serving path (WebLLM's PagedAttention analogue): attention
-layers keep physical page pools ``[P, page_size, Kv, Dh]``.  EVERY token
+The TPU-native serving path (WebLLM's PagedAttention analogue): every
+attention layer keeps its own head-major physical page pool ``[P, Kv,
+page_size, Dh_pad]`` (``head_dim`` padded to 128 lanes).  The pools are
+a list of per-layer arrays, so a layer's pool reaches its kernel as a
+whole buffer in the TPU's default layout: no per-layer slice copy and no
+relayout copy around the scatter or the kernel.  EVERY token
 — prompt or completion, cold or cache-hit — flows through the same paged
 machinery, and a whole engine step dispatches as ONE kernel call:
 
@@ -34,6 +38,7 @@ runner interface ``MLCEngine`` drives, adding the chunked-prefill calls
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -163,11 +168,16 @@ class PagedModelRunner:
         # loaded models with one number.  Derived from the actual pool
         # dtypes: bf16 K/V vectors by default; int8 vectors plus one
         # bf16 scale per (token, kv-head) when the pool is quantized.
+        # Pools pad head_dim with zero lanes to a multiple of 128: the
+        # kernel then reads them in the TPU's default layout, with no
+        # relayout copy of the pool around each call (see
+        # kernels/paged_attention.py), and the padded lanes count here.
+        self.pool_dh = -(-cfg.head_dim // 128) * 128
         kv_elem = 1 if self.kv_quant else jnp.dtype(jnp.bfloat16).itemsize
         scale_bytes = jnp.dtype(jnp.bfloat16).itemsize if self.kv_quant \
             else 0
         self.page_bytes = (2 * cfg.n_layers * page_size * cfg.n_kv_heads
-                           * (cfg.head_dim * kv_elem + scale_bytes))
+                           * (self.pool_dh * kv_elem + scale_bytes))
         self.prefix_cache = (
             PrefixCache(self.pm, max_cached_pages=max_cached_pages,
                         max_cached_bytes=max_cached_bytes,
@@ -225,24 +235,26 @@ class PagedModelRunner:
             from repro.quant.int4 import quantize_tree
             params = quantize_tree(params, model.params_def(cfg))
         self.params = params
-        L, Kv, Dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        L, Kv, Dh = cfg.n_layers, cfg.n_kv_heads, self.pool_dh
         # one extra physical page (index num_pages) absorbs the K/V
         # writes of a padded final chunk's pad rows — never in any
         # page table, never read
         self.trash_page = num_pages
         pool_dtype = jnp.int8 if self.kv_quant else jnp.bfloat16
-        self.k_pages = jnp.zeros((L, num_pages + 1, page_size, Kv, Dh),
-                                 pool_dtype)
-        self.v_pages = jnp.zeros_like(self.k_pages)
-        # per-(token, kv-head) dequant scale planes, mirroring the pool
-        # page layout so the page table routes them too.  In f32 mode
-        # they are tiny placeholders: every jit signature carries them
-        # (donated + rebound like the pools) so both modes share one
-        # call protocol, but no traced op ever touches them.
-        scale_shape = ((L, num_pages + 1, page_size, Kv)
-                       if self.kv_quant else (L, 1, 1, 1))
-        self.k_scales = jnp.zeros(scale_shape, jnp.bfloat16)
-        self.v_scales = jnp.zeros(scale_shape, jnp.bfloat16)
+        pool_shape = (num_pages + 1, Kv, page_size, Dh)
+        self.k_pages = [jnp.zeros(pool_shape, pool_dtype) for _ in range(L)]
+        self.v_pages = [jnp.zeros(pool_shape, pool_dtype) for _ in range(L)]
+        # per-(token, kv-head) dequant scale planes [P, page_size, Kv],
+        # paged like the pools so the page table routes them too.  In
+        # f32 mode they are tiny placeholders: every jit signature
+        # carries them (donated + rebound like the pools) so both modes
+        # share one call protocol, but no traced op ever touches them.
+        scale_shape = ((num_pages + 1, page_size, Kv)
+                       if self.kv_quant else (1, 1, 1))
+        self.k_scales = [jnp.zeros(scale_shape, jnp.bfloat16)
+                         for _ in range(L)]
+        self.v_scales = [jnp.zeros(scale_shape, jnp.bfloat16)
+                         for _ in range(L)]
         self._step = jax.jit(self._decode_step, donate_argnums=(1, 2, 3, 4))
         self._chunk_step = jax.jit(self._prefill_chunk_step,
                                    donate_argnums=(1, 2, 3, 4))
@@ -264,12 +276,11 @@ class PagedModelRunner:
                              "all_greedy", "need_logprobs", "use_counts"))
 
         def _copy(k, v, ks, vs, src, dst):
-            k = k.at[:, dst].set(k[:, src])
-            v = v.at[:, dst].set(v[:, src])
+            def page(pools):
+                return [x.at[dst].set(x[src]) for x in pools]
             if self.kv_quant:    # placeholders have no page dim to copy
-                ks = ks.at[:, dst].set(ks[:, src])
-                vs = vs.at[:, dst].set(vs[:, src])
-            return k, v, ks, vs
+                ks, vs = page(ks), page(vs)
+            return page(k), page(v), ks, vs
 
         # donated so XLA updates the pools in place instead of copying
         # the whole K/V buffers per CoW fork
@@ -308,33 +319,53 @@ class PagedModelRunner:
         q = jnp.clip(jnp.round(xf / scale[..., None]), -127, 127)
         return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
 
-    def _scatter_kv(self, k_pages, v_pages, k_scales, v_scales,
-                    li, page_idx, page_off, k, v):
-        """Scatter one layer's new K/V rows ([N, Kv, Dh]) into the page
-        pools — quantizing at scatter time (values + scales) when the
-        pool is int8.  The branch is on a Python flag, so each mode
-        traces to a single-path program."""
-        if self.kv_quant:
-            kq, ks = self._page_quant(k)
-            vq, vs = self._page_quant(v)
-            k_pages = k_pages.at[li, page_idx, page_off].set(kq)
-            v_pages = v_pages.at[li, page_idx, page_off].set(vq)
-            k_scales = k_scales.at[li, page_idx, page_off].set(ks)
-            v_scales = v_scales.at[li, page_idx, page_off].set(vs)
-        else:
-            k_pages = k_pages.at[li, page_idx, page_off].set(
-                k.astype(k_pages.dtype))
-            v_pages = v_pages.at[li, page_idx, page_off].set(
-                v.astype(v_pages.dtype))
-        return k_pages, v_pages, k_scales, v_scales
+    @staticmethod
+    def _scatter_rows(pool, page_idx, page_off, x):
+        """Write row n of ``x`` ([N, Kv, Dh], zero-padded to the pool's
+        lane width) to token slot ``page_off[n]`` of physical page
+        ``page_idx[n]`` of a head-major ``pool`` ([P, Kv, page_size,
+        pool_dh]), for every kv head.
 
-    def _layer_scales(self, k_scales, v_scales, li):
-        """Per-layer scale operands for the attention kernels: the real
-        planes when quantized, ``None`` (the unquantized kernel variant)
-        otherwise."""
+        The pool is viewed as ``[P * Kv, page_size, pool_dh]`` (a free
+        reshape of the leading dims), so the scatter indexes the two
+        major dims and writes whole lane rows: the form the TPU scatter
+        runs in place on the pool's own layout.  Indexing ``[page, :,
+        offset]`` directly makes XLA relayout (copy) the whole pool
+        around every scatter."""
+        P, Kv, ps, dh = pool.shape
+        n = page_idx.shape[0]
+        heads = (page_idx[:, None] * Kv
+                 + jnp.arange(Kv, dtype=page_idx.dtype)[None, :])
+        offs = jnp.broadcast_to(page_off[:, None], (n, Kv))
+        rows = jnp.pad(x.reshape(n * Kv, -1).astype(pool.dtype),
+                       ((0, 0), (0, dh - x.shape[-1])))
+        flat = pool.reshape(P * Kv, ps, dh).at[
+            heads.reshape(-1), offs.reshape(-1)].set(rows)
+        return flat.reshape(pool.shape)
+
+    def _scatter_kv(self, pools, li, page_idx, page_off, k, v):
+        """Scatter layer ``li``'s new K/V rows ([N, Kv, Dh]) into its
+        page pool — quantizing at scatter time (values + scales) when the
+        pool is int8.  ``pools`` is the ``(k_pages, v_pages, k_scales,
+        v_scales)`` tuple of per-layer lists, updated in place.  Returns
+        the layer's kernel operands ``(k_pool, v_pool, k_scales,
+        v_scales)``, the scales ``None`` (the unquantized kernel variant)
+        unless the pool is int8.  The branch is on a Python flag, so
+        each mode traces to a single-path program."""
+        kp, vp, ks, vs = pools
+        put = functools.partial(self._scatter_rows, page_idx=page_idx,
+                                page_off=page_off)
         if self.kv_quant:
-            return k_scales[li], v_scales[li]
-        return None, None
+            kq, kscale = self._page_quant(k)
+            vq, vscale = self._page_quant(v)
+            kp[li], vp[li] = put(kp[li], x=kq), put(vp[li], x=vq)
+            # scale planes are token-major [P, page_size, Kv]: one
+            # [Kv] row per token
+            ks[li] = ks[li].at[page_idx, page_off].set(kscale)
+            vs[li] = vs[li].at[page_idx, page_off].set(vscale)
+            return kp[li], vp[li], ks[li], vs[li]
+        kp[li], vp[li] = put(kp[li], x=k), put(vp[li], x=v)
+        return kp[li], vp[li], None, None
 
     def _decode_step(self, params, k_pages, v_pages, k_scales, v_scales,
                      token, pos, page_table, lens, page_idx, page_off):
@@ -344,6 +375,7 @@ class PagedModelRunner:
         B = token.shape[0]
         x = jnp.take(params["embed"], token, axis=0)           # [B,1,D]
         layers = self._layer_params_traced(params)
+        pools = tuple(map(list, (k_pages, v_pages, k_scales, v_scales)))
         for li, p in enumerate(layers):
             h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
             q = _project(cfg, p["attn"], h, "q", cfg.n_heads)  # [B,1,H,Dh]
@@ -353,12 +385,9 @@ class PagedModelRunner:
             q = apply_rope(q, pos[:, None], cfg.rope_theta)
             k = apply_rope(k, pos[:, None], cfg.rope_theta)
             # scatter the new K/V into each sequence's current page
-            k_pages, v_pages, k_scales, v_scales = self._scatter_kv(
-                k_pages, v_pages, k_scales, v_scales, li, page_idx,
-                page_off, k[:, 0], v[:, 0])
-            ks, vs = self._layer_scales(k_scales, v_scales, li)
-            att = paged_attention(q[:, 0], k_pages[li], v_pages[li],
-                                  page_table, lens,
+            kp, vp, ks, vs = self._scatter_kv(pools, li, page_idx,
+                                              page_off, k[:, 0], v[:, 0])
+            att = paged_attention(q[:, 0], kp, vp, page_table, lens,
                                   k_scales=ks, v_scales=vs)   # [B,H,Dh]
             y = qdot(att.reshape(B, 1, -1), p["attn"]["wo"])
             x = x + y
@@ -369,7 +398,7 @@ class PagedModelRunner:
             logits = x @ params["embed"].T
         else:
             logits = x @ params["lm_head"]
-        return logits, k_pages, v_pages, k_scales, v_scales
+        return (logits,) + pools
 
     def _prefill_chunk_step(self, params, k_pages, v_pages, k_scales,
                             v_scales, tokens, pos, page_table, ctx, start,
@@ -386,6 +415,7 @@ class PagedModelRunner:
         C = tokens.shape[0]
         x = jnp.take(params["embed"], tokens[None], axis=0)    # [1,C,D]
         layers = self._layer_params_traced(params)
+        pools = tuple(map(list, (k_pages, v_pages, k_scales, v_scales)))
         for li, p in enumerate(layers):
             h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
             q = _project(cfg, p["attn"], h, "q", cfg.n_heads)  # [1,C,H,Dh]
@@ -394,13 +424,10 @@ class PagedModelRunner:
             q, k = _qk_norm(cfg, p["attn"], q, k)
             q = apply_rope(q, pos[None, :], cfg.rope_theta)
             k = apply_rope(k, pos[None, :], cfg.rope_theta)
-            k_pages, v_pages, k_scales, v_scales = self._scatter_kv(
-                k_pages, v_pages, k_scales, v_scales, li, page_idx,
-                page_off, k[0], v[0])
-            ks, vs = self._layer_scales(k_scales, v_scales, li)
-            att = paged_prefill_attention(q[0], k_pages[li], v_pages[li],
-                                          page_table, ctx, start,
-                                          k_scales=ks,
+            kp, vp, ks, vs = self._scatter_kv(pools, li, page_idx,
+                                              page_off, k[0], v[0])
+            att = paged_prefill_attention(q[0], kp, vp, page_table, ctx,
+                                          start, k_scales=ks,
                                           v_scales=vs)         # [C,H,Dh]
             y = qdot(att.reshape(1, C, -1), p["attn"]["wo"])
             x = x + y
@@ -411,7 +438,7 @@ class PagedModelRunner:
             logits = x @ params["embed"].T
         else:
             logits = x @ params["lm_head"]
-        return logits[0], k_pages, v_pages, k_scales, v_scales
+        return (logits[0],) + pools
 
     def _ragged_logits(self, params, k_pages, v_pages, k_scales, v_scales,
                        tokens, pos, page_tables, contexts, starts, lengths,
@@ -435,6 +462,7 @@ class PagedModelRunner:
         C = N // B
         x = jnp.take(params["embed"], tokens[None], axis=0)    # [1,N,D]
         layers = self._layer_params_traced(params)
+        pools = tuple(map(list, (k_pages, v_pages, k_scales, v_scales)))
         for li, p in enumerate(layers):
             h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
             q = _project(cfg, p["attn"], h, "q", cfg.n_heads)  # [1,N,H,Dh]
@@ -443,14 +471,12 @@ class PagedModelRunner:
             q, k = _qk_norm(cfg, p["attn"], q, k)
             q = apply_rope(q, pos[None, :], cfg.rope_theta)
             k = apply_rope(k, pos[None, :], cfg.rope_theta)
-            k_pages, v_pages, k_scales, v_scales = self._scatter_kv(
-                k_pages, v_pages, k_scales, v_scales, li, page_idx,
-                page_off, k[0], v[0])
-            ks, vs = self._layer_scales(k_scales, v_scales, li)
+            kp, vp, ks, vs = self._scatter_kv(pools, li, page_idx,
+                                              page_off, k[0], v[0])
             att = paged_ragged_attention(
-                q[0].reshape(B, C, cfg.n_heads, cfg.head_dim),
-                k_pages[li], v_pages[li], page_tables, contexts,
-                starts, k_scales=ks, v_scales=vs)              # [B,C,H,Dh]
+                q[0].reshape(B, C, cfg.n_heads, cfg.head_dim), kp, vp,
+                page_tables, contexts, starts, k_scales=ks,
+                v_scales=vs)                                   # [B,C,H,Dh]
             y = qdot(att.reshape(1, N, -1), p["attn"]["wo"])
             x = x + y
             h = rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
@@ -460,8 +486,7 @@ class PagedModelRunner:
             logits = x @ params["embed"].T
         else:
             logits = x @ params["lm_head"]
-        return (logits[0].reshape(B, C, -1), k_pages, v_pages,
-                k_scales, v_scales)
+        return (logits[0].reshape(B, C, -1),) + pools
 
     def _ragged_step(self, params, k_pages, v_pages, k_scales, v_scales,
                      tokens, pos, page_tables, contexts, starts, lengths,

@@ -49,6 +49,10 @@ requests finish, then the replica is recycled (``recycles`` counter).
 The router reaches into its OWN backends only for supervisor-level
 setup (tokenizer + page size for affinity keys); the request path
 crosses the JSON port like any other frontend caller.
+
+Placement: replica ``i`` is built, and keeps stepping, on
+``jax.local_devices()[i]`` (wrapping round when replicas outnumber
+devices), so a four-chip host serves four replicas on four chips.
 """
 from __future__ import annotations
 
@@ -58,6 +62,8 @@ import time
 import uuid
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple, Union
+
+import jax
 
 from repro.core import api
 from repro.core.engine import EngineCrashed, MLCEngine
@@ -132,9 +138,12 @@ class RouterEngine:
         self.imbalance_limit = imbalance_limit
         self.affinity_capacity = affinity_capacity
         self._lock = threading.Lock()
+        #: replica ``slot`` lives on ``_devices[slot % len(_devices)]``:
+        #: one chip per replica while there are chips to go round
+        self._devices = jax.local_devices()
         self._replicas: List[_Replica] = []
         for slot in range(replicas):
-            backend = engine_factory()
+            backend = self._spawn(slot)
             front = ServiceWorkerMLCEngine(backend, replica_id=f"r{slot}")
             self._replicas.append(_Replica(slot, backend, front))
         # affinity keys mirror each replica's PrefixCache: tokenizer +
@@ -158,6 +167,20 @@ class RouterEngine:
                                                 name="repro-router-monitor",
                                                 daemon=True)
         self._monitor_thread.start()
+
+    def _spawn(self, slot: int) -> MLCEngine:
+        """Build replica ``slot``'s engine under ``jax.default_device`` of
+        its own chip: the factory's params and KV pools are allocated
+        there, and the engine keeps stepping there (it records the
+        device it was built under)."""
+        with jax.default_device(self._devices[slot % len(self._devices)]):
+            return self._factory()
+
+    def replica_engines(self) -> List[MLCEngine]:
+        """The replicas' current engines, by slot (for placement checks
+        and tests; requests go through the frontend API)."""
+        with self._lock:
+            return [r.backend for r in self._replicas]
 
     # -- placement -------------------------------------------------------
     def _prompt_keys(self, req: api.ChatCompletionRequest) -> List[tuple]:
@@ -370,7 +393,7 @@ class RouterEngine:
 
     def _respawn(self, rep: _Replica, counter: str):
         try:
-            backend = self._factory()
+            backend = self._spawn(rep.slot)
             front = ServiceWorkerMLCEngine(backend,
                                            replica_id=rep.replica_id)
         except Exception as e:

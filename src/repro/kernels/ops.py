@@ -103,12 +103,11 @@ def batched_accept(tokens, drafts, win_off):
 
 
 @functools.partial(jax.jit, static_argnames=("group", "block_m", "block_n",
-                                             "block_k", "interpret"))
+                                             "interpret"))
 def w4a16_gemm(x, w_packed, scales, *, group: int = 64, block_m: int = 128,
-               block_n: int = 128, block_k: int = 128,
-               interpret: Optional[bool] = None):
+               block_n: int = 128, interpret: Optional[bool] = None):
     return _w4a16(x, w_packed, scales, group=group, block_m=block_m,
-                  block_n=block_n, block_k=block_k, interpret=interpret)
+                  block_n=block_n, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows",

@@ -14,17 +14,25 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def _dequant_gather(pages, scales, table, flat_len):
-    """Gather ``pages[table]`` flattened to [..., flat_len, Kv, D] and —
-    when per-(token, kv-head) ``scales`` are given — dequantize in f32."""
-    D = pages.shape[-1]
-    Kv = pages.shape[-2]
-    lead = table.shape[:-1]
-    out = pages[table].reshape(*lead, flat_len, Kv, D).astype(jnp.float32)
-    if scales is not None:
-        s = scales[table].reshape(*lead, flat_len, Kv)
-        out = out * s[..., None].astype(jnp.float32)
-    return out
+def _gather(pages, table, d):
+    """Gather head-major ``pages[table]`` -> [..., pps, Kv, page_size, d]
+    in f32, dropping pool lane padding past the head dim ``d``.  Token t
+    of the sequence is (page t // page_size, slot t % page_size); the
+    einsums keep the two apart, so pages are never transposed into token
+    order."""
+    return pages[table][..., :d].astype(jnp.float32)
+
+
+def _token_scales(scales, table, lead: int):
+    """Per-(token, kv-head) int8 scales ([P, page_size, Kv]) of the
+    gathered pages, laid out to multiply scores/probabilities
+    [*lead, Kv, G, pps, page_size] (``lead`` leading batch dims): scaling
+    q . k by the key's scale equals dequantizing k first, and scaling
+    p by the value's scale equals dequantizing v."""
+    s = scales[table].astype(jnp.float32)          # [*lead, pps, ps, Kv]
+    n = s.ndim
+    s = jnp.moveaxis(s, n - 1, lead)               # [*lead, Kv, pps, ps]
+    return jnp.expand_dims(s, lead + 1)            # [*lead, Kv, 1, pps, ps]
 
 
 def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -58,27 +66,33 @@ def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         scale: Optional[float] = None) -> jax.Array:
     """Decode attention over a paged KV cache.
 
-    q: [B, H, D]; k_pages/v_pages: [P, page_size, Kv, D];
+    q: [B, H, D]; k_pages/v_pages: [P, Kv, page_size, Dp >= D];
     page_table: [B, pages_per_seq] int32; context_lens: [B] int32.
     Optional k_scales/v_scales ([P, page_size, Kv]) dequantize int8 pools.
     """
     B, H, D = q.shape
-    P, page_size, Kv, _ = k_pages.shape
+    P, Kv, page_size, _ = k_pages.shape
     pages_per_seq = page_table.shape[1]
     G = H // Kv
     scale = D ** -0.5 if scale is None else scale
 
-    # gather each sequence's pages -> [B, pages_per_seq*page_size, Kv, D]
+    # each sequence's pages: [B, pages_per_seq, Kv, page_size, D]
     flat = pages_per_seq * page_size
-    k = _dequant_gather(k_pages, k_scales, page_table, flat)
-    v = _dequant_gather(v_pages, v_scales, page_table, flat)
+    k = _gather(k_pages, page_table, D)
+    v = _gather(v_pages, page_table, D)
     qf = q.reshape(B, Kv, G, D).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,btkd->bkgt", qf, k) * scale
-    t = jnp.arange(pages_per_seq * page_size)[None, :]
+    scores = jnp.einsum("bkgd,bpksd->bkgps", qf, k) * scale
+    if k_scales is not None:
+        scores = scores * _token_scales(k_scales, page_table, 1)
+    scores = scores.reshape(B, Kv, G, flat)
+    t = jnp.arange(flat)[None, :]
     valid = t < context_lens[:, None]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", p, v.astype(jnp.float32))
+    p = jax.nn.softmax(scores, axis=-1).reshape(B, Kv, G, pages_per_seq,
+                                                page_size)
+    if v_scales is not None:
+        p = p * _token_scales(v_scales, page_table, 1)
+    out = jnp.einsum("bkgps,bpksd->bkgd", p, v)
     return out.reshape(B, H, D).astype(q.dtype)
 
 
@@ -91,27 +105,34 @@ def paged_prefill_attention_ref(q: jax.Array, k_pages: jax.Array,
     """Chunked prefill attention over one sequence's paged KV cache.
 
     q: [C, H, D] (chunk of queries at positions start..start+C-1);
-    k_pages/v_pages: [P, page_size, Kv, D]; page_table: [pages_per_seq].
+    k_pages/v_pages: [P, Kv, page_size, Dp >= D];
+    page_table: [pages_per_seq].
     Keys at t >= context are masked; query row i sees keys t <= start+i.
     Optional k_scales/v_scales ([P, page_size, Kv]) dequantize int8 pools.
     """
     C, H, D = q.shape
-    P, page_size, Kv, _ = k_pages.shape
+    P, Kv, page_size, _ = k_pages.shape
     pages_per_seq = page_table.shape[0]
     G = H // Kv
     scale = D ** -0.5 if scale is None else scale
 
     flat = pages_per_seq * page_size
-    k = _dequant_gather(k_pages, k_scales, page_table, flat)
-    v = _dequant_gather(v_pages, v_scales, page_table, flat)
+    k = _gather(k_pages, page_table, D)
+    v = _gather(v_pages, page_table, D)
     qf = q.reshape(C, Kv, G, D).astype(jnp.float32)
-    scores = jnp.einsum("ckgd,tkd->ckgt", qf, k) * scale
-    t = jnp.arange(pages_per_seq * page_size)[None, :]
+    scores = jnp.einsum("ckgd,pksd->ckgps", qf, k) * scale
+    if k_scales is not None:
+        scores = scores * _token_scales(k_scales, page_table, 0)
+    scores = scores.reshape(C, Kv, G, flat)
+    t = jnp.arange(flat)[None, :]
     qpos = start + jnp.arange(C)[:, None]
     mask = (t < context) & (t <= qpos)
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("ckgt,tkd->ckgd", p, v.astype(jnp.float32))
+    p = jax.nn.softmax(scores, axis=-1).reshape(C, Kv, G, pages_per_seq,
+                                                page_size)
+    if v_scales is not None:
+        p = p * _token_scales(v_scales, page_table, 0)
+    out = jnp.einsum("ckgps,pksd->ckgd", p, v)
     return out.reshape(C, H, D).astype(q.dtype)
 
 
@@ -133,17 +154,20 @@ def paged_ragged_attention_ref(q: jax.Array, k_pages: jax.Array,
     Optional k_scales/v_scales ([P, page_size, Kv]) dequantize int8 pools.
     """
     B, C, H, D = q.shape
-    P, page_size, Kv, _ = k_pages.shape
+    P, Kv, page_size, _ = k_pages.shape
     pages_per_seq = page_tables.shape[1]
     G = H // Kv
     scale = D ** -0.5 if scale is None else scale
 
     flat = pages_per_seq * page_size
-    k = _dequant_gather(k_pages, k_scales, page_tables, flat)
-    v = _dequant_gather(v_pages, v_scales, page_tables, flat)
+    k = _gather(k_pages, page_tables, D)
+    v = _gather(v_pages, page_tables, D)
     qf = q.reshape(B, C, Kv, G, D).astype(jnp.float32)
-    scores = jnp.einsum("bckgd,btkd->bckgt", qf, k) * scale
-    t = jnp.arange(pages_per_seq * page_size)[None, None, :]
+    scores = jnp.einsum("bckgd,bpksd->bckgps", qf, k) * scale
+    if k_scales is not None:
+        scores = scores * _token_scales(k_scales, page_tables, 1)[:, None]
+    scores = scores.reshape(B, C, Kv, G, flat)
+    t = jnp.arange(flat)[None, None, :]
     qpos = starts[:, None] + jnp.arange(C)[None, :]         # [B, C]
     mask = (t < contexts[:, None, None]) & (t <= qpos[..., None])
     scores = jnp.where(mask[:, :, None, None, :], scores, NEG_INF)
@@ -151,7 +175,10 @@ def paged_ragged_attention_ref(q: jax.Array, k_pages: jax.Array,
     # fully masked rows (batch padding, contexts == 0) -> zeros, not the
     # uniform distribution softmax degenerates to
     p = jnp.where(jnp.any(mask, -1)[:, :, None, None, None], p, 0.0)
-    out = jnp.einsum("bckgt,btkd->bckgd", p, v.astype(jnp.float32))
+    p = p.reshape(B, C, Kv, G, pages_per_seq, page_size)
+    if v_scales is not None:
+        p = p * _token_scales(v_scales, page_tables, 1)[:, None]
+    out = jnp.einsum("bckgps,bpksd->bckgd", p, v)
     return out.reshape(B, C, H, D).astype(q.dtype)
 
 

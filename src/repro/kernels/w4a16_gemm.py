@@ -12,7 +12,8 @@ the ``dot``, fp32 VMEM accumulator across the sequential K grid dim.
     out      [M, N]   bf16
 
 Grid: (M/bm, N/bn, K/bk) — K innermost/sequential.  ``bk`` is a multiple
-of the quant group size so each K-tile sees whole groups.
+of the quant group size so each K-tile sees whole groups.  On TPU every
+2-D quantized matmul of the serving path runs here (``int4.qdot``).
 """
 from __future__ import annotations
 
@@ -24,30 +25,36 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
-
-def _kernel(x_ref, w_ref, s_ref, o_ref, acc_scr, *,
-            block_k: int, group: int):
+def _kernel(xe_ref, xo_ref, w_ref, s_ref, o_ref, acc_scr, *,
+            half_group: int, dot_dtype):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    packed = w_ref[...]                               # [bk/2, bn] int8
-    low = jnp.right_shift(jnp.left_shift(packed, 4), 4)
+    # unpack in int32: Mosaic has no int8 shifts.  Row i of the packed
+    # tile holds k = 2i (low nibble) and k = 2i + 1 (high nibble); the
+    # wrapper split x into its even and odd columns to match, so no
+    # sublane interleave is needed
+    packed = w_ref[...].astype(jnp.int32)             # [bk/2, bn]
+    low = jnp.right_shift(jnp.left_shift(packed, 28), 28)
     high = jnp.right_shift(packed, 4)
-    wq = jnp.stack([low, high], axis=1)               # [bk/2, 2, bn]
-    wq = wq.reshape(block_k, -1)                      # [bk, bn]
-    scales = s_ref[...]                               # [bk/G, bn]
-    w = (wq.reshape(block_k // group, group, -1).astype(jnp.float32)
-         * scales.astype(jnp.float32)[:, None, :])
-    w = w.reshape(block_k, -1).astype(jnp.bfloat16)   # [bk, bn]
-    x = x_ref[...]                                    # [bm, bk]
-    acc_scr[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    scales = s_ref[...].astype(jnp.float32)           # [bk/G, bn]
+
+    def dequant(q):                                   # [bk/2, bn]
+        rows = q.shape[0]
+        w = (q.astype(jnp.float32).reshape(rows // half_group, half_group,
+                                           -1) * scales[:, None, :])
+        return w.reshape(rows, -1).astype(jnp.bfloat16).astype(dot_dtype)
+
+    def dot(x_ref, w):
+        return jax.lax.dot_general(
+            x_ref[...].astype(dot_dtype), w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    acc_scr[...] += dot(xe_ref, dequant(low)) + dot(xo_ref, dequant(high))
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
@@ -56,27 +63,46 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_scr, *,
 
 def w4a16_gemm(x: jax.Array, w_packed: jax.Array, scales: jax.Array, *,
                group: int = 64, block_m: int = 128, block_n: int = 128,
-               block_k: int = 128,
                interpret: Optional[bool] = None) -> jax.Array:
+    """``x @ dequant(w_packed, scales)`` for any row count M.
+
+    Rows are padded up to a whole number of ``block_m`` tiles (a
+    multiple of 16 when M is small).  ``block_k`` is ``max(8 * group,
+    256)`` when that divides K, else all of K, so the scale block
+    ``(block_k // group, block_n)`` keeps its second-minor dim a multiple
+    of 8 and the half-K blocks of x stay whole 128-lane tiles — the
+    tiling Mosaic lowers.  N must be a multiple of ``block_n`` or at
+    most it."""
     M, K = x.shape
     K2, N = w_packed.shape
-    assert K == 2 * K2, (K, K2)
-    block_m = min(block_m, M)
+    assert K == 2 * K2 and K % group == 0 and group % 2 == 0, \
+        (K, K2, group)
+    block_m = min(block_m, -(-M // 16) * 16)
     block_n = min(block_n, N)
-    block_k = min(block_k, K)
-    if block_k % group:
-        block_k = group
-    assert (M % block_m == 0 and N % block_n == 0 and K % block_k == 0
-            and block_k % group == 0), (M, N, K, block_m, block_n, block_k)
+    block_k = max(8 * group, 256)
+    if K % block_k:
+        block_k = K
+    assert N % block_n == 0, (N, block_n)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    Mp = -(-M // block_m) * block_m
+    if Mp != M:
+        x = jnp.pad(x, ((0, Mp - M), (0, 0)))
+    x_even, x_odd = x[:, 0::2], x[:, 1::2]            # [Mp, K/2] each
 
-    grid = (M // block_m, N // block_n, K // block_k)
+    grid = (Mp // block_m, N // block_n, K // block_k)
+    x_spec = pl.BlockSpec((block_m, block_k // 2),
+                          lambda mi, ni, ki: (mi, ki))
     out = pl.pallas_call(
-        functools.partial(_kernel, block_k=block_k, group=group),
+        # XLA:CPU cannot run every bf16 x bf16 -> f32 dot the Pallas
+        # interpreter emits; bf16 products are exact in f32, so the
+        # interpreter's f32 dot computes the same numbers
+        functools.partial(_kernel, half_group=group // 2,
+                          dot_dtype=jnp.float32 if interpret
+                          else jnp.bfloat16),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
+            x_spec, x_spec,
             pl.BlockSpec((block_k // 2, block_n),
                          lambda mi, ni, ki: (ki, ni)),
             pl.BlockSpec((block_k // group, block_n),
@@ -84,10 +110,10 @@ def w4a16_gemm(x: jax.Array, w_packed: jax.Array, scales: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni, ki: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((Mp, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w_packed, scales)
-    return out
+    )(x_even, x_odd, w_packed, scales)
+    return out[:M]

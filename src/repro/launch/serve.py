@@ -42,6 +42,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_repo_compile_cache
+    use_repo_compile_cache()
     from repro.configs import get_config
     from repro.core import (ChatCompletionRequest, ChatMessage, MLCEngine,
                             RouterEngine, ServiceWorkerMLCEngine)

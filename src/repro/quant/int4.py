@@ -172,24 +172,21 @@ def qdot(x: jax.Array, w: Any) -> jax.Array:
     """Matmul with a maybe-quantized RHS: plain ``x @ w`` for ordinary
     arrays, W4A16 for :class:`QTensor` weights.
 
-    On TPU with MXU-tile-aligned 2-D shapes the packed weight feeds the
-    Pallas ``w4a16_gemm`` kernel directly (the weight stays 4-bit in
-    HBM; dequant is fused into the K loop).  Elsewhere — interpret-mode
-    hosts, stacked (scanned) weights, ragged shapes — it falls back to
+    On TPU every 2-D quantized weight feeds the Pallas ``w4a16_gemm``
+    kernel directly (the weight stays 4-bit in HBM; dequant is fused
+    into the K loop; the kernel pads the rows itself).  Stacked
+    (scanned) weights, and every weight on hosts without a TPU, take
     ``x @ w.dequant()``.  Consumers (projections, MLP, paged runner)
     call this instead of ``@`` so a quantized tree serves unchanged.
     """
     if not is_qtensor(w):
         return x @ w
     if jax.default_backend() == "tpu" and w.data.ndim == 2:
-        K, N = w.shape[-2], w.shape[-1]
+        from repro.kernels.ops import w4a16_gemm
+        K, N = w.shape
         lead = x.shape[:-1]
-        M = 1
-        for s in lead:
-            M *= int(s)
-        if M % 128 == 0 and N % 128 == 0 and K % 128 == 0:
-            from repro.kernels.ops import w4a16_gemm
-            y = w4a16_gemm(x.reshape(M, K).astype(jnp.bfloat16),
-                           w.data, w.scales, group=w.group)
-            return y.reshape(*lead, N).astype(x.dtype)
+        y = w4a16_gemm(x.reshape(-1, K).astype(jnp.bfloat16),
+                       w.data, w.scales, group=w.group,
+                       block_n=128 if N % 128 == 0 else N)
+        return y.reshape(*lead, N).astype(x.dtype)
     return x @ w.dequant()

@@ -53,8 +53,8 @@ def test_flash_attention_sliding(window, rng_key):
 def test_paged_attention_sweep(B, H, Kv, D, pages, psz, pps, rng_key):
     ks = jax.random.split(rng_key, 5)
     q = _rand(ks[0], (B, H, D), jnp.bfloat16)
-    kp = _rand(ks[1], (pages, psz, Kv, D), jnp.bfloat16)
-    vp = _rand(ks[2], (pages, psz, Kv, D), jnp.bfloat16)
+    kp = _rand(ks[1], (pages, Kv, psz, D), jnp.bfloat16)
+    vp = _rand(ks[2], (pages, Kv, psz, D), jnp.bfloat16)
     pt = jax.random.randint(ks[3], (B, pps), 0, pages)
     lens = jax.random.randint(ks[4], (B,), 1, pps * psz + 1)
     out = ops.paged_attention(q, kp, vp, pt, lens, interpret=True)
@@ -75,8 +75,8 @@ def test_paged_prefill_attention_sweep(C, H, Kv, D, pages, psz, pps,
     padded final chunk (only the valid rows are compared)."""
     ks = jax.random.split(rng_key, 4)
     q = _rand(ks[0], (C, H, D), jnp.bfloat16)
-    kp = _rand(ks[1], (pages, psz, Kv, D), jnp.bfloat16)
-    vp = _rand(ks[2], (pages, psz, Kv, D), jnp.bfloat16)
+    kp = _rand(ks[1], (pages, Kv, psz, D), jnp.bfloat16)
+    vp = _rand(ks[2], (pages, Kv, psz, D), jnp.bfloat16)
     pt = jax.random.randint(ks[3], (pps,), 0, pages)
     start = int(start_frac * (pps * psz - C))
     for valid in (C, max(1, C // 2)):      # full chunk + padded chunk
@@ -102,8 +102,8 @@ def test_paged_ragged_attention_sweep(B, C, H, Kv, D, pages, psz, pps,
     and one fully padded batch row (context 0 -> zeros)."""
     ks = jax.random.split(rng_key, 5)
     q = _rand(ks[0], (B, C, H, D), jnp.bfloat16)
-    kp = _rand(ks[1], (pages, psz, Kv, D), jnp.bfloat16)
-    vp = _rand(ks[2], (pages, psz, Kv, D), jnp.bfloat16)
+    kp = _rand(ks[1], (pages, Kv, psz, D), jnp.bfloat16)
+    vp = _rand(ks[2], (pages, Kv, psz, D), jnp.bfloat16)
     pt = jax.random.randint(ks[3], (B, pps), 0, pages)
     # row kinds cycle: decode, full chunk, partial chunk, batch pad
     lengths = [(1, C, max(1, C // 2), 0)[b % 4] for b in range(B)]
@@ -135,11 +135,12 @@ def test_paged_ragged_attention_sweep(B, C, H, Kv, D, pages, psz, pps,
 # ---------------------------------------------------------------------------
 
 def _quant_pools(kp, vp):
-    """Per-(token, kv-head) symmetric int8, exactly the runner's scheme."""
+    """Per-(token, kv-head) symmetric int8, exactly the runner's scheme:
+    head-major int8 pools, token-major [P, page_size, Kv] scales."""
     from repro.core.paged_runner import PagedModelRunner
     kq, ks = PagedModelRunner._page_quant(kp)
     vq, vs = PagedModelRunner._page_quant(vp)
-    return kq, ks, vq, vs
+    return kq, ks.swapaxes(1, 2), vq, vs.swapaxes(1, 2)
 
 
 @pytest.mark.parametrize("B,H,Kv,D,pages,psz,pps", [
@@ -149,8 +150,8 @@ def _quant_pools(kp, vp):
 def test_paged_attention_quantized(B, H, Kv, D, pages, psz, pps, rng_key):
     ks_ = jax.random.split(rng_key, 5)
     q = _rand(ks_[0], (B, H, D), jnp.bfloat16)
-    kp = _rand(ks_[1], (pages, psz, Kv, D), jnp.bfloat16)
-    vp = _rand(ks_[2], (pages, psz, Kv, D), jnp.bfloat16)
+    kp = _rand(ks_[1], (pages, Kv, psz, D), jnp.bfloat16)
+    vp = _rand(ks_[2], (pages, Kv, psz, D), jnp.bfloat16)
     pt = jax.random.randint(ks_[3], (B, pps), 0, pages)
     lens = jax.random.randint(ks_[4], (B,), 1, pps * psz + 1)
     kq, kscale, vq, vscale = _quant_pools(kp, vp)
@@ -173,8 +174,8 @@ def test_paged_prefill_attention_quantized(C, H, Kv, D, pages, psz, pps,
                                            rng_key):
     ks_ = jax.random.split(rng_key, 4)
     q = _rand(ks_[0], (C, H, D), jnp.bfloat16)
-    kp = _rand(ks_[1], (pages, psz, Kv, D), jnp.bfloat16)
-    vp = _rand(ks_[2], (pages, psz, Kv, D), jnp.bfloat16)
+    kp = _rand(ks_[1], (pages, Kv, psz, D), jnp.bfloat16)
+    vp = _rand(ks_[2], (pages, Kv, psz, D), jnp.bfloat16)
     pt = jax.random.randint(ks_[3], (pps,), 0, pages)
     start = (pps * psz - C) // 2
     ctx = start + C
@@ -202,8 +203,8 @@ def test_paged_ragged_attention_quantized(B, C, H, Kv, D, pages, psz, pps,
     scale-multiply inside the page loop (no materialized f32 copy)."""
     ks_ = jax.random.split(rng_key, 5)
     q = _rand(ks_[0], (B, C, H, D), jnp.bfloat16)
-    kp = _rand(ks_[1], (pages, psz, Kv, D), jnp.bfloat16)
-    vp = _rand(ks_[2], (pages, psz, Kv, D), jnp.bfloat16)
+    kp = _rand(ks_[1], (pages, Kv, psz, D), jnp.bfloat16)
+    vp = _rand(ks_[2], (pages, Kv, psz, D), jnp.bfloat16)
     pt = jax.random.randint(ks_[3], (B, pps), 0, pages)
     lengths = [(1, C, max(1, C // 2), 0)[b % 4] for b in range(B)]
     starts = np.array(jax.random.randint(
@@ -233,8 +234,8 @@ def test_paged_ragged_attention_quantized(B, C, H, Kv, D, pages, psz, pps,
 def test_paged_attention_single_token_context(rng_key):
     ks = jax.random.split(rng_key, 3)
     q = _rand(ks[0], (1, 4, 64), jnp.bfloat16)
-    kp = _rand(ks[1], (4, 8, 2, 64), jnp.bfloat16)
-    vp = _rand(ks[2], (4, 8, 2, 64), jnp.bfloat16)
+    kp = _rand(ks[1], (4, 2, 8, 64), jnp.bfloat16)
+    vp = _rand(ks[2], (4, 2, 8, 64), jnp.bfloat16)
     pt = jnp.zeros((1, 2), jnp.int32)
     lens = jnp.ones((1,), jnp.int32)
     out = ops.paged_attention(q, kp, vp, pt, lens, interpret=True)
@@ -248,6 +249,7 @@ def test_paged_attention_single_token_context(rng_key):
     (256, 512, 256, 64),
     (128, 128, 384, 32),
     (64, 1024, 128, 128),
+    (5, 512, 256, 64),            # decode-sized M: rows padded in-kernel
 ])
 def test_w4a16_gemm_sweep(M, K, N, G, rng_key):
     ks = jax.random.split(rng_key, 2)

@@ -181,8 +181,8 @@ def test_int8_decode_matches_f32(rng_key):
     """Plain prefill+decode with int8 pages tracks the f32 runner."""
     pr = _runner(rng_key, kv_dtype="int8")
     ref = _runner(rng_key)
-    assert pr.k_pages.dtype == jnp.int8
-    assert pr.k_scales.shape[1:] == (33, 4, pr.cfg.n_kv_heads)
+    assert pr.k_pages[0].dtype == jnp.int8
+    assert pr.k_scales[0].shape == (33, 4, pr.cfg.n_kv_heads)
     prompt = list(range(1, 12))
     a, b = pr.prefill_seq(prompt), ref.prefill_seq(prompt)
     _close_and_same_argmax(pr.last_prefill_logits(),

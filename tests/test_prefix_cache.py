@@ -137,11 +137,12 @@ def test_max_cached_bytes_cap():
 
 def test_max_cached_bytes_engine_knob():
     """load_model(max_cached_bytes=...) reaches the cache with the
-    model's real per-page KV byte cost."""
+    model's real per-page KV byte cost (head_dim padded to the pools'
+    128-lane width)."""
     cfg = get_config("llama-3.1-8b", reduced=True)
     eng = MLCEngine()
-    page_bytes = (2 * cfg.n_layers * 16 * cfg.n_kv_heads
-                  * cfg.head_dim * 2)
+    dh = -(-cfg.head_dim // 128) * 128
+    page_bytes = 2 * cfg.n_layers * 16 * cfg.n_kv_heads * dh * 2
     eng.load_model("m", cfg, max_slots=2, max_context=128, seed=0,
                    backend="paged", page_size=16,
                    max_cached_bytes=2 * page_bytes)
@@ -154,19 +155,20 @@ def test_max_cached_bytes_engine_knob():
 def test_page_bytes_tracks_pool_dtype():
     """page_bytes is derived from the ACTUAL pool dtype: bf16 K/V
     vectors by default; int8 vectors plus one bf16 scale per (token,
-    kv-head) when the pool is quantized.  The same byte cap therefore
-    admits ~2x the pages on a quantized pool (Dh=64: 128 B vs 66 B per
-    KV vector pair)."""
+    kv-head) when the pool is quantized, at the pools' 128-lane padded
+    head width.  The same byte cap therefore admits ~2x the pages on a
+    quantized pool (256 B vs 130 B per padded KV vector pair)."""
     from repro.core.paged_runner import PagedModelRunner
     cfg = get_config("llama-3.1-8b", reduced=True)
     psz = 16
     kw = dict(num_pages=4, page_size=psz, max_slots=1, pages_per_seq=2)
     bf16 = PagedModelRunner(cfg, **kw)
     i8 = PagedModelRunner(cfg, kv_dtype="int8", **kw)
+    dh = -(-cfg.head_dim // 128) * 128
     assert bf16.page_bytes == (2 * cfg.n_layers * psz * cfg.n_kv_heads
-                               * cfg.head_dim * 2)
+                               * dh * 2)
     assert i8.page_bytes == (2 * cfg.n_layers * psz * cfg.n_kv_heads
-                             * (cfg.head_dim + 2))
+                             * (dh + 2))
     assert bf16.page_bytes / i8.page_bytes >= 1.8
     # the engine knob path sees the quantized cost too
     eng = MLCEngine()
@@ -239,7 +241,7 @@ def test_cow_isolation_between_branches(runner):
     full, tail = runner.prefix_cache.match(shared)
     assert tail is not None
     src_page = tail[0]
-    snapshot = np.asarray(runner.k_pages[:, src_page])
+    snapshot = np.stack([np.asarray(k[src_page]) for k in runner.k_pages])
 
     b = runner.prefill_seq(shared + [50, 51])
     c = runner.prefill_seq(shared + [60, 61, 62])
@@ -247,7 +249,7 @@ def test_cow_isolation_between_branches(runner):
     for step in range(3):
         out = runner.decode({b: 70 + step, c: 80 + step})
         assert all(np.isfinite(v).all() for v in out.values())
-    after = np.asarray(runner.k_pages[:, src_page])
+    after = np.stack([np.asarray(k[src_page]) for k in runner.k_pages])
     np.testing.assert_array_equal(snapshot, after)
     # the two branches forked *different* private tail pages
     pages_b = runner.pm.seqs[b].pages
