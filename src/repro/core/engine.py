@@ -55,6 +55,8 @@ worker-thread split of the paper lives one level up, in core/worker.py).
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import queue
 import threading
@@ -66,6 +68,7 @@ from typing import Dict, Iterator, List, Optional, Union
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.core import api
 from repro.core.paged_cache import OutOfPages
@@ -610,7 +613,8 @@ class MLCEngine:
                                     continue
                                 self._thread = None
                                 return
-                        self._wake.wait(timeout=0.05)
+                        with TraceAnnotation("engine.idle"):
+                            self._wake.wait(timeout=0.05)
                         self._wake.clear()
         except BaseException as e:
             # step() already contains the per-batch failure handling; an
@@ -683,10 +687,7 @@ class MLCEngine:
             # at the end of the previous iteration
             plan, lm.next_plan = lm.next_plan, None
             if plan is None:
-                plan = sched.plan_step(
-                    lm.token_budget, chunk_size=lm.prefill_chunk_size,
-                    admission_info=lambda r: self._probe(lm, r),
-                    draft_k=lm.draft_k)
+                plan = self._plan(lm)
             return busy | self._step_fused(lm, plan)
         plan = sched.plan_step(
             lm.token_budget, chunk_size=None,
@@ -718,6 +719,14 @@ class MLCEngine:
         if work:
             lm.exec_steps += 1
         return busy | work
+
+    def _plan(self, lm: _LoadedModel):
+        """Plan one fused step (span ``engine.plan``)."""
+        with TraceAnnotation("engine.plan"):
+            return lm.scheduler.plan_step(
+                lm.token_budget, chunk_size=lm.prefill_chunk_size,
+                admission_info=lambda r: self._probe(lm, r),
+                draft_k=lm.draft_k)
 
     def _preempt_newest(self, lm: _LoadedModel):
         """Graceful degradation on OutOfPages: kick the newest request
@@ -887,24 +896,38 @@ class MLCEngine:
         must not starve it into an OutOfPages preempt/restart loop.
         Flush discipline: grammar packing, OutOfPages preemption, and
         poisoned-dispatch eviction all drain the in-flight handle
-        before touching sequence/page state it still references."""
-        rows, srcs = self._plan_rows(lm, plan)
-        if lm.inflight is not None and self._needs_flush(rows):
-            self._drain(lm)
-            # the drain may have finished sequences or completed
-            # prefills: rebuild (now with host tokens throughout)
+        before touching sequence/page state it still references.
+
+        Spans: ``engine.rows`` covers the revalidation and admissions;
+        ``engine.step`` opens once the step has rows, so an iteration
+        that dispatches nothing (an idle poll, or the drain that retires
+        the last in-flight step) is no step."""
+        with TraceAnnotation("engine.rows"):
             rows, srcs = self._plan_rows(lm, plan)
-        for r, first in plan.admit:
-            rows.extend(self._bind_admission(lm, r, first))
+            if lm.inflight is not None and self._needs_flush(rows):
+                self._drain(lm)
+                # the drain may have finished sequences or completed
+                # prefills: rebuild (now with host tokens throughout)
+                rows, srcs = self._plan_rows(lm, plan)
+            for r, first in plan.admit:
+                rows.extend(self._bind_admission(lm, r, first))
         if not rows:
             if lm.inflight is not None:
                 self._drain(lm)    # nothing to overlap: retire the lag
                 return True
             return False
+        with StepTraceAnnotation("engine.step", step_num=lm.exec_steps):
+            return self._dispatch_step(lm, rows, srcs)
+
+    def _dispatch_step(self, lm: _LoadedModel, rows: List[tuple],
+                       srcs: Dict[int, int]) -> bool:
+        """Pack and dispatch one step's rows, drain the previous step
+        while the device computes, and (depth 2) plan the next."""
         while True:
             try:
-                batch, consumers, n_top = self._pack_sampling(
-                    lm, rows, srcs)
+                with TraceAnnotation("engine.pack"):
+                    batch, consumers, n_top = self._pack_sampling(
+                        lm, rows, srcs)
                 break
             except _GrammarDeadEnd as e:
                 # fail ONLY the dead-ended requests (loudly, like the
@@ -1000,10 +1023,7 @@ class MLCEngine:
             self._drain(lm)            # sequential semantics
         else:
             # plan step N+1 behind the device, from post-drain state
-            lm.next_plan = lm.scheduler.plan_step(
-                lm.token_budget, chunk_size=lm.prefill_chunk_size,
-                admission_info=lambda r: self._probe(lm, r),
-                draft_k=lm.draft_k)
+            lm.next_plan = self._plan(lm)
         return True
 
     def _drain(self, lm: _LoadedModel):
@@ -1012,6 +1032,7 @@ class MLCEngine:
         if h is not None:
             self._drain_one(lm, h)
 
+    @functools.partial(jax.profiler.annotate_function, name="engine.drain")
     def _drain_one(self, lm: _LoadedModel, h: _Inflight):
         """Materialize a dispatched step and run its host-side
         consumption — detok, streaming, finish detection, grammar
@@ -1226,7 +1247,9 @@ class MLCEngine:
             sched.waiting.appendleft(r)        # conditions changed; retry
             return None
         if r.t_admit == 0.0:
-            r.t_admit = time.time()
+            with _request_span("engine.admit", r):
+                r.t_admit = time.time()
+                sched.count_queue_wait(r.t_admit - r.t_submit)
         return pending, shared
 
     def _bind_admission(self, lm: _LoadedModel, r: _Request,
@@ -1735,27 +1758,28 @@ class MLCEngine:
 
     def _finish_request(self, r: _Request):
         """All choices done: emit the aggregate result + sentinel."""
-        req = r.req
-        if req.stream:
-            r.out.put(_SENTINEL)
-        else:
-            choices = []
-            for s in sorted(r.seqs, key=lambda s: s.index):
-                msg = api.ChatMessage(
-                    "assistant",
-                    None if s.finish_reason == "tool_calls" else s.text,
-                    tool_calls=s.tool_calls)
-                choice = api.Choice(message=msg, index=s.index,
-                                    finish_reason=s.finish_reason)
-                if req.logprobs:
-                    choice.logprobs = api.Logprobs(content=s.logprobs)
-                choices.append(choice)
-            r.out.put(api.ChatCompletionResponse(
-                id=r.rid, model=r.model, choices=choices,
-                usage=self._usage(r)))
-            r.out.put(_SENTINEL)
-        with self._lock:
-            self._retire(r.rid)
+        with _request_span("engine.finish", r):
+            req = r.req
+            if req.stream:
+                r.out.put(_SENTINEL)
+            else:
+                choices = []
+                for s in sorted(r.seqs, key=lambda s: s.index):
+                    msg = api.ChatMessage(
+                        "assistant",
+                        None if s.finish_reason == "tool_calls" else s.text,
+                        tool_calls=s.tool_calls)
+                    choice = api.Choice(message=msg, index=s.index,
+                                        finish_reason=s.finish_reason)
+                    if req.logprobs:
+                        choice.logprobs = api.Logprobs(content=s.logprobs)
+                    choices.append(choice)
+                r.out.put(api.ChatCompletionResponse(
+                    id=r.rid, model=r.model, choices=choices,
+                    usage=self._usage(r)))
+                r.out.put(_SENTINEL)
+            with self._lock:
+                self._retire(r.rid)
 
     # -- result plumbing ---------------------------------------------------
     def _next_item(self, r: _Request):
@@ -1818,10 +1842,13 @@ class MLCEngine:
                            "drafted": ..., "accepted": ...,
                            "accept_rate": ...},
              "scheduler": {"waiting": ..., "running": ..., "plans": ...,
-                           "admitted": ..., "preemptions": ..., "pages": ...},
+                           "admitted": ..., "preemptions": ...,
+                           "queue_wait_s": ..., "queue_waits": ...,
+                           "pages": ...},
              "runner":    {"attn_kernel_calls": ..., "ragged_steps": ...,
                            "prefill_tokens": ..., "decode_tokens": ...,
-                           "pages": {...}, "prefix_cache": {...}, ...}}
+                           "bucket_tokens": ..., "pages": {...},
+                           "prefix_cache": {...}, ...}}
 
         ``runner.attn_kernel_calls / engine.exec_steps`` is the
         dispatch-fusion figure of merit — 1.0 on the paged backend.
@@ -1852,6 +1879,15 @@ class MLCEngine:
     def shutdown(self):
         self._shutdown = True
         self._wake.set()
+
+
+def _request_span(name: str, r: _Request):
+    """A span carrying the request's id, so that one request can be
+    followed through a trace; the id is formatted only while a profiler
+    trace is recording."""
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, request_id=r.rid)
+    return contextlib.nullcontext()
 
 
 def _lp_entry(tok, cls, i: int, lp: float):

@@ -44,7 +44,8 @@ class PageManager:
     # engine loop thread; ``stats``/``num_free_pages`` are the len-only
     # probes other threads may call.
     _THREAD_CONFINED = ("free_pages", "free_slots", "seqs", "ref",
-                        "_next_id", "n_shared", "n_cow_forks")
+                        "_next_id", "n_shared", "n_cow_forks",
+                        "n_live_token_steps")
     _CROSS_THREAD = ("stats", "num_free_pages")
 
     def __init__(self, num_pages: int, page_size: int, max_slots: int,
@@ -59,6 +60,8 @@ class PageManager:
         self._next_id = 0
         self.n_shared = 0                      # pages adopted zero-copy
         self.n_cow_forks = 0                   # tail pages forked CoW
+        #: tokens held by live sequences, summed over ragged steps
+        self.n_live_token_steps = 0
         # hooks installed by the prefix cache: reclaim(n) tries to evict
         # cached pages back to the free list; evictable() reports how many
         # it could free on demand (for admission accounting).
@@ -176,6 +179,12 @@ class PageManager:
         while len(alloc.pages) > need:
             self.deref_page(alloc.pages.pop())
 
+    def count_live_tokens(self):
+        """Add the tokens that live sequences hold to
+        ``live_token_steps``; the runner calls it once per ragged step,
+        so over a span of steps it gives the pool's mean occupancy."""
+        self.n_live_token_steps += sum(a.length for a in self.seqs.values())
+
     # -- views -----------------------------------------------------------
     def page_table(self, seq_ids: List[int]) -> np.ndarray:
         """[len(seq_ids), pages_per_seq] int32 (0-padded)."""
@@ -202,8 +211,11 @@ class PageManager:
         return len(self.free_pages) + extra
 
     def stats(self) -> dict:
-        return {"free_pages": len(self.free_pages),
+        return {"num_pages": self.num_pages,
+                "page_size": self.page_size,
+                "free_pages": len(self.free_pages),
                 "used_pages": self.num_pages - len(self.free_pages),
+                "live_token_steps": self.n_live_token_steps,
                 "active_seqs": len(self.seqs),
                 "shared_pages": self.n_shared,
                 "cow_forks": self.n_cow_forks}

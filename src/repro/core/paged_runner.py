@@ -113,9 +113,13 @@ class StepHandle:
         if self.result is not None:
             return self.result
         r = self.runner
-        t0 = time.perf_counter()
-        tok = np.asarray(self.tokens)          # blocks until step done
-        r.t_block_s += time.perf_counter() - t0
+        # the span times what t_block_s counts: the wait for the step
+        # and the token pull; the other pulls are the caller's own time
+        with jax.profiler.TraceAnnotation("runner.materialize"):
+            t0 = time.perf_counter()
+            self.tokens.block_until_ready()
+            tok = np.asarray(self.tokens)
+            r.t_block_s += time.perf_counter() - t0
         res = SampleResult(
             tokens=tok[:self.n_rows],
             logprob=np.asarray(self.logprob)[:self.n_rows],
@@ -192,6 +196,8 @@ class PagedModelRunner:
         self.n_decode_steps = 0           # batched decode steps
         self.n_decode_tokens = 0          # tokens decoded across the batch
         self.n_ragged_steps = 0           # fused ragged kernel steps
+        #: token slots of the padded (B, C) buckets of those steps
+        self.n_bucket_tokens = 0
         self.n_sampled_tokens = 0         # tokens sampled ON DEVICE
         #: logit ROWS ([V] float vectors) pulled device→host — 0 on the
         #: fused engine path, where only sampled token ids cross back
@@ -693,6 +699,8 @@ class PagedModelRunner:
             b *= 2
         return b
 
+    @functools.partial(jax.profiler.annotate_function,
+                       name="runner.dispatch")
     def run_step(self, rows: List[Tuple[int, List[int], str]],
                  sampling: Optional[SamplingParamsBatch] = None,
                  n_top: int = 0, return_logits: bool = True,
@@ -763,6 +771,7 @@ class PagedModelRunner:
         Bb = self._bucket(B)
         Cb = self._bucket(max(len(toks) for _, toks, _ in rows))
         N = Bb * Cb
+        self.n_bucket_tokens += N
         tok = np.zeros(N, np.int32)
         tok_src = np.full(N, -1, np.int32)   # >= 0: take prev_tokens[src]
         pos = np.zeros(N, np.int32)
@@ -794,6 +803,7 @@ class PagedModelRunner:
                 # a speculative verify row's draft tail (offsets 1..k)
                 # is host-known and packed normally
                 tok_src[o] = decode_srcs[b]
+        self.pm.count_live_tokens()
         attn_args = (jnp.asarray(tok), jnp.asarray(pos),
                      jnp.asarray(page_tables), jnp.asarray(contexts),
                      jnp.asarray(starts), jnp.asarray(lengths),
@@ -1194,6 +1204,7 @@ class PagedModelRunner:
                "decode_steps": self.n_decode_steps,
                "decode_tokens": self.n_decode_tokens,
                "ragged_steps": self.n_ragged_steps,
+               "bucket_tokens": self.n_bucket_tokens,
                "sampled_tokens": self.n_sampled_tokens,
                "host_logit_rows": self.host_logit_rows,
                "host_sync_bytes": self.host_sync_bytes,

@@ -168,7 +168,8 @@ class Scheduler:
     # thread-safe deque shared with submitter threads by design.
     _THREAD_CONFINED = ("running", "free_slots", "_admit_seq",
                         "_admitted_at", "_group_of", "_outranked",
-                        "n_plans", "n_admitted", "n_preemptions")
+                        "n_plans", "n_admitted", "n_preemptions",
+                        "queue_wait_s", "n_queue_waits")
     _CROSS_THREAD = ("enqueue", "stats")
 
     def __init__(self, *, max_slots: int, max_context: int,
@@ -188,6 +189,10 @@ class Scheduler:
         self.n_plans = 0
         self.n_admitted = 0
         self.n_preemptions = 0
+        #: summed seconds from submission to first admission, and the
+        #: number of requests they cover
+        self.queue_wait_s = 0.0
+        self.n_queue_waits = 0
 
     def enqueue(self, item):
         self.waiting.append(item)
@@ -417,6 +422,12 @@ class Scheduler:
         self.n_preemptions += 1
         return group, released
 
+    def count_queue_wait(self, seconds: float):
+        """Count one request's wait from its submission to its first
+        admission; the engine calls it once per request."""
+        self.queue_wait_s += seconds
+        self.n_queue_waits += 1
+
     @property
     def active_slots(self) -> List[int]:
         return sorted(self.running)
@@ -425,7 +436,9 @@ class Scheduler:
         out = {"waiting": len(self.waiting), "running": len(self.running),
                "free_slots": len(self.free_slots),
                "plans": self.n_plans, "admitted": self.n_admitted,
-               "preemptions": self.n_preemptions}
+               "preemptions": self.n_preemptions,
+               "queue_wait_s": self.queue_wait_s,
+               "queue_waits": self.n_queue_waits}
         if self.pm is not None:
             out["pages"] = self.pm.stats()
         return out

@@ -214,6 +214,7 @@ def paged_ragged_attention(q: jax.Array, k_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="paged_ragged_attention",
     )(*operands)
     return (out.reshape(B, Kv, C, G, D).transpose(0, 2, 1, 3, 4)
             .reshape(B, C, H, D)[..., :Dq])

@@ -81,6 +81,29 @@ def test_paged_ragged_attention_lowers(one_chip, B, C, dtype):
              _spec(one_chip, (B,), i32), s)
 
 
+def test_paged_ragged_attention_keeps_its_name(one_chip):
+    """The kernel's operation is named ``paged_ragged_attention`` in the
+    compiled program whatever jitted function calls it: a profiler trace
+    names device ops after it, and the benchmark finds the kernel's
+    device time by that name."""
+    k, v, _ = _pools(one_chip, jnp.bfloat16)
+    i32 = jnp.int32
+
+    def serving_step(q, k, v, pt, ctx, st):
+        return 2 * paged_ragged_attention(q, k, v, pt, ctx, st,
+                                          interpret=False)
+
+    text = _compile(serving_step, _spec(one_chip, (4, 1, H, D), jnp.bfloat16),
+                    k, v, _spec(one_chip, (4, PPS), i32),
+                    _spec(one_chip, (4,), i32),
+                    _spec(one_chip, (4,), i32)).as_text()
+    ops = [line.split(" = ")[0].strip().lstrip("%")
+           for line in text.splitlines()
+           if "tpu_custom_call" in line and " = " in line]
+    assert ops and all(op.rsplit(".", 1)[0] == "paged_ragged_attention"
+                       for op in ops), ops
+
+
 def test_paged_decode_attention_lowers(one_chip):
     k, v, _ = _pools(one_chip, jnp.bfloat16)
     _compile(lambda q, k, v, pt, n: paged_attention(
