@@ -89,10 +89,38 @@ def test_paged_prefill_attention_sweep(C, H, Kv, D, pages, psz, pps,
             np.asarray(expect, np.float32)[:valid], atol=0.06)
 
 
+def _ragged_pools(key, pages, Kv, psz, D):
+    """Head-major pools; an unaligned head dim (phi-3.5's 96) rides in
+    zero-padded 128-lane pools, as the runner's do."""
+    Dp = -(-D // 128) * 128 if D % 64 else D
+    kk, kv = jax.random.split(key)
+    pad = ((0, 0),) * 3 + ((0, Dp - D),)
+    return (jnp.pad(_rand(kk, (pages, Kv, psz, D), jnp.bfloat16), pad),
+            jnp.pad(_rand(kv, (pages, Kv, psz, D), jnp.bfloat16), pad))
+
+
+def _ragged_rows(key, B, C, psz, pps):
+    """Row kinds cycle decode, full chunk, padded partial chunk, batch
+    pad (context 0); the first decode row fills its whole page table
+    and the first full chunk ends mid-page.  Returns (lengths, contexts,
+    starts)."""
+    lengths = [(1, C, max(1, C // 2), 0)[b % 4] for b in range(B)]
+    starts = np.array(jax.random.randint(
+        key, (B,), 0, pps * psz - C + 1), np.int32)
+    starts[np.asarray(lengths) == 0] = 0
+    starts[0] = pps * psz - 1
+    if B > 1:
+        starts[1] = pps * psz - C - psz // 2
+    contexts = (starts + np.asarray(lengths)).astype(np.int32)
+    return lengths, jnp.asarray(contexts), jnp.asarray(starts)
+
+
 @pytest.mark.parametrize("B,C,H,Kv,D,pages,psz,pps", [
     (4, 8, 8, 2, 64, 16, 16, 4),
     (2, 16, 4, 4, 128, 32, 8, 6),
     (8, 4, 2, 1, 64, 16, 16, 2),
+    (8, 16, 32, 32, 96, 80, 16, 8),       # phi-3.5 width: MHA, D 96
+    (4, 256, 32, 8, 64, 48, 16, 18),      # GQA tile of 1024 rows: hb < Kv
 ])
 def test_paged_ragged_attention_sweep(B, C, H, Kv, D, pages, psz, pps,
                                       rng_key):
@@ -100,21 +128,15 @@ def test_paged_ragged_attention_sweep(B, C, H, Kv, D, pages, psz, pps,
     single-sequence chunk oracle over its own page table — for a mixed
     batch of decode rows (length 1), full chunks, padded partial chunks,
     and one fully padded batch row (context 0 -> zeros)."""
-    ks = jax.random.split(rng_key, 5)
+    ks = jax.random.split(rng_key, 4)
     q = _rand(ks[0], (B, C, H, D), jnp.bfloat16)
-    kp = _rand(ks[1], (pages, Kv, psz, D), jnp.bfloat16)
-    vp = _rand(ks[2], (pages, Kv, psz, D), jnp.bfloat16)
-    pt = jax.random.randint(ks[3], (B, pps), 0, pages)
-    # row kinds cycle: decode, full chunk, partial chunk, batch pad
-    lengths = [(1, C, max(1, C // 2), 0)[b % 4] for b in range(B)]
-    starts = np.array(jax.random.randint(
-        ks[4], (B,), 0, pps * psz - C + 1), np.int32)
-    starts[np.asarray(lengths) == 0] = 0
-    contexts = (starts + np.asarray(lengths)).astype(np.int32)
-    out = ops.paged_ragged_attention(q, kp, vp, pt, jnp.asarray(contexts),
-                                     jnp.asarray(starts), interpret=True)
-    batched = ref.paged_ragged_attention_ref(
-        q, kp, vp, pt, jnp.asarray(contexts), jnp.asarray(starts))
+    kp, vp = _ragged_pools(ks[1], pages, Kv, psz, D)
+    pt = jax.random.randint(ks[2], (B, pps), 0, pages)
+    lengths, contexts, starts = _ragged_rows(ks[3], B, C, psz, pps)
+    out = ops.paged_ragged_attention(q, kp, vp, pt, contexts, starts,
+                                     interpret=True)
+    batched = ref.paged_ragged_attention_ref(q, kp, vp, pt, contexts,
+                                             starts)
     for b, L in enumerate(lengths):
         got = np.asarray(out[b], np.float32)
         if L == 0:
@@ -196,30 +218,25 @@ def test_paged_prefill_attention_quantized(C, H, Kv, D, pages, psz, pps,
 @pytest.mark.parametrize("B,C,H,Kv,D,pages,psz,pps", [
     (4, 8, 8, 2, 64, 16, 16, 4),
     (2, 16, 4, 4, 128, 32, 8, 6),
+    (8, 16, 32, 32, 96, 80, 16, 8),       # phi-3.5 width: MHA, D 96
+    (4, 256, 32, 8, 64, 48, 16, 18),      # GQA tile of 1024 rows: hb < Kv
 ])
 def test_paged_ragged_attention_quantized(B, C, H, Kv, D, pages, psz, pps,
                                           rng_key):
     """The serving kernel: mixed decode/chunk/pad rows over int8 pools,
     scale-multiply inside the page loop (no materialized f32 copy)."""
-    ks_ = jax.random.split(rng_key, 5)
+    ks_ = jax.random.split(rng_key, 4)
     q = _rand(ks_[0], (B, C, H, D), jnp.bfloat16)
-    kp = _rand(ks_[1], (pages, Kv, psz, D), jnp.bfloat16)
-    vp = _rand(ks_[2], (pages, Kv, psz, D), jnp.bfloat16)
-    pt = jax.random.randint(ks_[3], (B, pps), 0, pages)
-    lengths = [(1, C, max(1, C // 2), 0)[b % 4] for b in range(B)]
-    starts = np.array(jax.random.randint(
-        ks_[4], (B,), 0, pps * psz - C + 1), np.int32)
-    starts[np.asarray(lengths) == 0] = 0
-    contexts = (starts + np.asarray(lengths)).astype(np.int32)
+    kp, vp = _ragged_pools(ks_[1], pages, Kv, psz, D)
+    pt = jax.random.randint(ks_[2], (B, pps), 0, pages)
+    lengths, contexts, starts = _ragged_rows(ks_[3], B, C, psz, pps)
     kq, kscale, vq, vscale = _quant_pools(kp, vp)
     out = ops.paged_ragged_attention(
-        q, kq, vq, pt, jnp.asarray(contexts), jnp.asarray(starts),
-        k_scales=kscale, v_scales=vscale, interpret=True)
+        q, kq, vq, pt, contexts, starts, k_scales=kscale, v_scales=vscale,
+        interpret=True)
     oracle = ref.paged_ragged_attention_ref(
-        q, kq, vq, pt, jnp.asarray(contexts), jnp.asarray(starts),
-        k_scales=kscale, v_scales=vscale)
-    dense = ref.paged_ragged_attention_ref(
-        q, kp, vp, pt, jnp.asarray(contexts), jnp.asarray(starts))
+        q, kq, vq, pt, contexts, starts, k_scales=kscale, v_scales=vscale)
+    dense = ref.paged_ragged_attention_ref(q, kp, vp, pt, contexts, starts)
     for b, L in enumerate(lengths):
         got = np.asarray(out[b], np.float32)
         if L == 0:

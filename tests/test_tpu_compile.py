@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
-from repro.kernels.paged_attention import (paged_attention,
+from repro.kernels.paged_attention import (head_block, paged_attention,
                                            paged_prefill_attention,
                                            paged_ragged_attention)
 from repro.kernels.w4a16_gemm import w4a16_gemm
@@ -26,6 +26,7 @@ CFG = get_config("phi-3.5-mini")
 KV, H, D = CFG.n_kv_heads, CFG.n_heads, CFG.head_dim
 PAGE, PAGES, PPS = 16, 385, 64          # 4 slots x 1024 tokens + headroom
 DP = 128                                # pools pad head_dim to 128 lanes
+CELL_PAGES, CELL_PPS = 769, 128         # the benchmark cells' pools
 
 
 @pytest.fixture(scope="module")
@@ -56,20 +57,28 @@ def _compile(fn, *args):
     return compiled
 
 
-def _pools(sh, dtype):
-    pool = _spec(sh, (PAGES, KV, PAGE, DP), dtype)
+def _pools(sh, dtype, pages=PAGES):
+    pool = _spec(sh, (pages, KV, PAGE, DP), dtype)
     if dtype != jnp.int8:
         return pool, pool, None
-    return pool, pool, _spec(sh, (PAGES, PAGE, KV), jnp.bfloat16)
+    return pool, pool, _spec(sh, (pages, PAGE, KV), jnp.bfloat16)
 
 
-@pytest.mark.parametrize("B,C,dtype", [
-    (4, 1, jnp.bfloat16),         # pure decode step
-    (4, 16, jnp.bfloat16),        # mixed decode + prefill-chunk step
-    (4, 16, jnp.int8),            # quantized pool, fused dequant
-], ids=["ragged-bf16-decode", "ragged-bf16-mixed", "ragged-int8"])
-def test_paged_ragged_attention_lowers(one_chip, B, C, dtype):
-    k, v, s = _pools(one_chip, dtype)
+@pytest.mark.parametrize("B,C,dtype,pages,pps", [
+    (4, 1, jnp.bfloat16, PAGES, PPS),       # pure decode step
+    (4, 16, jnp.bfloat16, PAGES, PPS),      # mixed decode + chunk step
+    (4, 16, jnp.int8, PAGES, PPS),          # quantized pool, fused dequant
+    # the cells' widest chunk buckets: the tallest query tiles, where
+    # the head block must shrink to fit VMEM
+    (8, 256, jnp.bfloat16, CELL_PAGES, CELL_PPS),
+    (16, 256, jnp.bfloat16, CELL_PAGES, CELL_PPS),
+    (8, 256, jnp.int8, CELL_PAGES, CELL_PPS),
+    (16, 256, jnp.int8, CELL_PAGES, CELL_PPS),
+], ids=["ragged-bf16-decode", "ragged-bf16-mixed", "ragged-int8",
+        "ragged-bf16-8x256", "ragged-bf16-16x256", "ragged-int8-8x256",
+        "ragged-int8-16x256"])
+def test_paged_ragged_attention_lowers(one_chip, B, C, dtype, pages, pps):
+    k, v, s = _pools(one_chip, dtype, pages)
     i32 = jnp.int32
 
     def step(q, k, v, pt, ctx, st, s):
@@ -77,8 +86,23 @@ def test_paged_ragged_attention_lowers(one_chip, B, C, dtype):
                                       v_scales=s, interpret=False)
 
     _compile(step, _spec(one_chip, (B, C, H, D), jnp.bfloat16), k, v,
-             _spec(one_chip, (B, PPS), i32), _spec(one_chip, (B,), i32),
+             _spec(one_chip, (B, pps), i32), _spec(one_chip, (B,), i32),
              _spec(one_chip, (B,), i32), s)
+
+
+def test_head_block_fits_the_tile():
+    """Every kv head of a page in one grid step for a decode tile; a
+    proper divisor of Kv for the tallest chunk tile; a GQA tile of 256
+    tokens x 4 query heads (the kernel sweep's GQA case) splits its 8
+    kv heads."""
+    bf16 = jnp.bfloat16
+    assert head_block(1, PAGE, DP, KV, bf16, bf16) == KV
+    assert head_block(1, PAGE, DP, KV, bf16, jnp.int8) == KV
+    for kv_dtype in (bf16, jnp.int8):
+        hb = head_block(256, PAGE, DP, KV, bf16, kv_dtype)
+        assert KV % hb == 0 and 1 <= hb < KV
+    hb = head_block(256 * 4, PAGE, DP, 8, bf16, bf16)
+    assert 8 % hb == 0 and hb < 8
 
 
 def test_paged_ragged_attention_keeps_its_name(one_chip):
