@@ -4,13 +4,18 @@ Everything a cell is made of is found by name: ``BENCHMARK.json`` names
 the cell's configuration file and traffic mix, the traffic mix is
 ``bench/traffic/<traffic>.json``, the plain reference is
 ``bench/references/<reference>.py`` as the configuration file names it,
-and each metric is read by ``bench/metrics/<metric>.py``.  A cell or a
-metric is added with files and an entry in ``BENCHMARK.json`` alone.
+and each metric is read by ``bench/metrics/<metric>.py``.  The
+configuration file states the program's model beyond its plain sizes
+(layer kinds and their sub-configurations) in ``program``, and its
+reference counts the model's operations and bytes.  A configuration, a
+cell or a metric is added with files and an entry in ``BENCHMARK.json``
+alone.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import gc
-import importlib
 import importlib.util
 import json
 import os
@@ -18,7 +23,8 @@ import shutil
 import tempfile
 import threading
 import time
-from dataclasses import dataclass, field
+import typing
+from dataclasses import dataclass
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
@@ -81,31 +87,96 @@ def load_cell(spec: dict, name: str, root: Path = ROOT) -> Cell:
     return Cell(name, w["chips"], conf, traffic, e2e, per_layer, root)
 
 
-def reader(name: str, root: Path = ROOT) -> Callable:
-    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+def _module(path: Path, prefix: str):
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-def reference(conf: dict):
-    return importlib.import_module(f"bench.references.{conf['reference']}")
+def reader(name: str, root: Path = ROOT) -> Callable:
+    """The ``read(run)`` function of ``bench/metrics/<name>.py``."""
+    return _module(root / "bench" / "metrics" / f"{name}.py",
+                   "bench_metric_").read
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_at(path: Path):
+    return _module(path, "bench_reference_")
+
+
+def reference(conf: dict, root: Path = ROOT):
+    """The plain reference ``bench/references/<reference>.py`` that the
+    configuration file names, from the cell's root.  Besides the model it
+    holds the model's counts: ``attention_flops``, ``attention_bytes``
+    and ``model_flops`` of the window's rows."""
+    return _reference_at(
+        root / "bench" / "references" / f"{conf['reference']}.py")
 
 
 # -- the system under test --------------------------------------------------
-def program_config(conf: dict):
-    """The program's model configuration, from the configuration file."""
+def _layer_pattern(pattern: list) -> tuple:
+    from repro.configs import base
+    kinds = (typing.get_args(base.MixerKind), typing.get_args(base.FFNKind))
+    for spec in pattern:
+        for kind, known in zip(spec, kinds):
+            if kind not in known:
+                raise ValueError(f"program: layer kind {kind!r} is none of "
+                                 f"{known}")
+    return tuple(base.LayerSpec(*spec) for spec in pattern)
+
+
+def _program(prog: dict) -> dict:
+    """``ModelConfig`` fields from a configuration file's ``program``:
+    ``layer_pattern`` as ``[mixer, ffn]`` pairs, a sub-configuration
+    (``moe``, ``mla``, ``mamba``, ``rwkv6``, ...) as an object of its
+    fields, any other field as given."""
     from repro.configs.base import ModelConfig
-    s = reference(conf).sizes_of(conf)
-    return ModelConfig(
+    hints = typing.get_type_hints(ModelConfig)
+    out = {}
+    for k, v in prog.items():
+        if k not in hints:
+            raise ValueError(f"program: {k!r} is no field of ModelConfig")
+        sub = [t for t in (hints[k], *typing.get_args(hints[k]))
+               if dataclasses.is_dataclass(t)]
+        if k == "layer_pattern":
+            v = _layer_pattern(v)
+        elif sub and isinstance(v, dict):
+            known = {f.name for f in dataclasses.fields(sub[0])}
+            unknown = sorted(set(v) - known)
+            if unknown:
+                raise ValueError(f"program: {k!r} ({sub[0].__name__}) has no "
+                                 f"field {', '.join(unknown)}")
+            v = sub[0](**v)
+        out[k] = v
+    return out
+
+
+def program_config(conf: dict, root: Path = ROOT):
+    """The program's model configuration, from the configuration file:
+    the plain sizes its reference reads, and over them ``program``."""
+    from repro.configs.base import ModelConfig
+    s = reference(conf, root).sizes_of(conf)
+    kw = dict(
         name=conf["name"], n_layers=s.layers, d_model=s.d, n_heads=s.heads,
         n_kv_heads=s.kv_heads, head_dim=s.head_dim, d_ff=s.d_ff,
         vocab_size=s.vocab, rope_theta=s.rope_theta, norm_eps=s.eps,
         act=conf["hidden_act"], tie_embeddings=conf["tie_word_embeddings"],
         max_context=conf["max_position_embeddings"], source=conf["source"])
+    prog = conf.get("program", {})
+    # the sizes the reference reads are the published keys'; the program
+    # may restate only the head size (a latent attention's differs)
+    fixed = sorted(set(prog) & (set(kw) - {"head_dim"}))
+    if fixed:
+        raise ValueError(f"program: {', '.join(fixed)} comes from the "
+                         f"published keys, not from program")
+    kw.update(_program(prog))
+    n = len(kw.get("layer_pattern", ()))
+    if n and n != kw["n_layers"]:
+        raise ValueError(f"program: layer_pattern has {n} layers, the "
+                         f"configuration {kw['n_layers']}")
+    return ModelConfig(**kw)
 
 
 def warm_buckets(cell: Cell) -> List[tuple]:
@@ -178,6 +249,9 @@ class Run:
     stats_close: dict
     setup_s: float
     peak: dict
+    #: what counts the model's operations and bytes: the configuration's
+    #: reference (``attention_flops``, ``attention_bytes``, ``model_flops``)
+    ref: object
     trace: Optional[object] = None
 
     def window_rows(self) -> List[tuple]:
@@ -222,7 +296,7 @@ def check_answers(cell: Cell, sizes, key, records: List[Record], seed: int,
     widest gap over the sample.  With ``control`` the same positions are
     read for the token that the reference in int8, and in fp8, puts
     first (the controls)."""
-    ref = reference(cell.conf)
+    ref = reference(cell.conf, cell.root)
     tok = IdTokenizer(sizes.vocab)
     ck = cell.traffic["check"]
     done = _finished_greedy(records)
@@ -317,7 +391,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
     import jax
     from repro.core import MLCEngine
     t0 = clock() if t0 is None else t0
-    ref = reference(cell.conf)
+    ref = reference(cell.conf, cell.root)
     sizes = ref.sizes_of(cell.conf)
     key = ref.seed_key(seed)
     dev = jax.devices()[0]
@@ -336,8 +410,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
     tok = IdTokenizer(sizes.vocab)
     eng = MLCEngine()
     try:
-        eng.load_model("m", program_config(cell.conf), params=params,
-                       tokenizer=tok, backend="paged", **cell.serving)
+        eng.load_model("m", program_config(cell.conf, cell.root),
+                       params=params, tokenizer=tok, backend="paged",
+                       **cell.serving)
         del params
         runner = eng.models["m"].runner.runner
         n = runner.warmup(sizes.vocab, buckets=warm_buckets(cell),
@@ -392,7 +467,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
         f"{ms.get('bytes_limit')}")
 
     run = Run(cell, sizes, records, t_open, t_close, st_open, st_close,
-              setup_s, peak)
+              setup_s, peak, ref)
     if trace:
         run.trace = tr["trace"]
     d = lambda *path: delta(st_open, st_close, *path)
@@ -409,8 +484,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
                  if t_open <= t < t_close})
     gaps = sorted(np.diff(ts).tolist(), reverse=True)
     long = [g for g in gaps if g > 0.25]
+    at = ts[int(np.argmax(np.diff(ts)))] - t_open if gaps else 0.0
     log(f"window stalls: the longest gaps between streamed tokens "
-        f"{[round(1000 * g, 1) for g in gaps[:5]]} ms, {len(long)} over "
+        f"{[round(1000 * g, 1) for g in gaps[:5]]} ms (the longest from "
+        f"{at:.3f} s into the window), {len(long)} over "
         f"250 ms ({sum(long):.3f} s); Python's "
         f"garbage collector ran {gcs.n} times in the window for "
         f"{1000 * gcs.seconds:.1f} ms, the longest "
@@ -424,8 +501,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool = False,
                      ) / max(steps, 1)
     log(f"window steps: {steps} "
         f"({1000 * (t_close - t_open) / max(steps, 1):.2f} ms each on the "
-        f"host clock; host {per('host_ms_per_step'):.2f} ms and dispatch "
-        f"gap {per('dispatch_gap_ms'):.2f} ms a step), decode tokens "
+        f"host clock; host {per('host_ms_per_step'):.2f} ms a step), "
+        f"decode tokens "
         f"{d('runner', 'decode_tokens')}, prefill tokens "
         f"{d('runner', 'prefill_tokens')}, preemptions "
         f"{d('scheduler', 'preemptions')}")

@@ -7,8 +7,6 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-from bench import flops
-
 
 def output_tok_s(run) -> float:
     """Output tokens streamed inside the window over its seconds."""
@@ -68,27 +66,29 @@ def idle_share(run) -> Optional[float]:
 def kernel_roofline(run, kernel: Sequence[str]) -> Optional[float]:
     """Least time the chip needs for the attention that the window's
     tokens required (the larger of its flops over the peak flop rate and
-    its bytes over the peak bandwidth) over the kernel's device time in
-    the traced window, in %."""
+    its bytes over the peak bandwidth, as the configuration's reference
+    counts them) over the kernel's device time in the traced window,
+    in %."""
     if run.trace is None:
         return None
     rows = run.window_rows()
     secs, calls = run.trace.op_time_s(kernel)
     if calls == 0 or not rows:
         return None
-    s, p = run.sizes, run.peak
-    need = max(flops.attention_flops(s, rows) / p["bf16_flops_per_s"],
-               flops.attention_bytes(s, rows) / p["hbm_bytes_per_s"])
+    s, p, c = run.sizes, run.peak, run.ref
+    need = max(c.attention_flops(s, rows) / p["bf16_flops_per_s"],
+               c.attention_bytes(s, rows) / p["hbm_bytes_per_s"])
     return 100 * need / secs
 
 
 def mfu(run, programs: Sequence[str]) -> Optional[float]:
-    """Model flops that the window's tokens required over the device time
+    """Model flops that the window's tokens required (as the
+    configuration's reference counts them) over the device time
     of the step program's runs in the traced window at the chip's peak,
     in %."""
     runs = _step_runs(run, programs)
     rows = run.window_rows()
     if runs is None or not rows:
         return None
-    f = flops.model_flops(run.sizes, rows)
+    f = run.ref.model_flops(run.sizes, rows)
     return 100 * f / (sum(runs) * run.peak["bf16_flops_per_s"])

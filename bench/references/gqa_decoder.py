@@ -11,6 +11,9 @@ cache or batching of the system under test, and importing nothing of it.
 Weights are random, made from the seed one layer at a time by
 :func:`layer_weights`, so the reference rebuilds layer ``l`` on its own
 and gets the same values the benchmark handed to the program.
+
+The model's counts, which the roofline and ``mfu`` readers take from a
+configuration's reference, are :mod:`bench.flops`' dense GQA formulas.
 """
 from __future__ import annotations
 
@@ -20,6 +23,9 @@ from typing import Dict, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.flops import (attention_bytes, attention_flops,  # noqa: F401
+                         model_flops)
 
 STD = 0.02                     # initializer_range of both published configs
 

@@ -32,7 +32,7 @@ def _old_stats():
 def _run(open_, close, trace=None):
     return harness.Run(cell=None, sizes=None, records=[], t_open=0.0,
                        t_close=1.0, stats_open=open_, stats_close=close,
-                       setup_s=1.0, peak={}, trace=trace)
+                       setup_s=1.0, peak={}, ref=None, trace=trace)
 
 
 def test_counter_readers():

@@ -1,4 +1,7 @@
 """The per-layer readers' arithmetic on a run and a trace made by hand."""
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -12,6 +15,7 @@ S = Sizes(layers=2, d=64, heads=4, kv_heads=2, head_dim=16, d_ff=128,
           vocab=512, rope_theta=1e4, eps=1e-5)
 PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
 MS = 1e6
+ROOT = Path(__file__).resolve().parents[2]
 
 
 def _stats(steps, host_ms, decode, ragged):
@@ -39,7 +43,7 @@ def _run(trace=None):
         cell=None, sizes=S, records=RECORDS, t_open=0.5, t_close=3.0,
         stats_open=_stats(10, 2.0, 40, 10),
         stats_close=_stats(20, 3.0, 60, 20), setup_s=7.0, peak=PEAK,
-        trace=trace)
+        ref=flops, trace=trace)
 
 
 def test_window_rows_from_the_requests():
@@ -86,3 +90,13 @@ def test_trace_readers():
     # two step runs of 4 ms
     assert harness.reader("mfu.decode")(r) == pytest.approx(
         100 * flops.model_flops(S, ROWS) / (8e-3 * 1e12))
+
+
+def test_trace_readers_count_with_the_configurations_reference():
+    conf = json.loads((ROOT / "bench/configs/phi-3.5-mini.json").read_text())
+    r = _run(_trace())
+    r.ref = harness.reference(conf)
+    for name in ("paged_attention_roofline.decode", "mfu.decode"):
+        got = harness.reader(name)(r)
+        assert got is not None
+        assert got == harness.reader(name)(_run(_trace()))
