@@ -54,7 +54,8 @@ from repro.core.prefix_cache import PrefixCache
 from repro.core.sampler import SampleResult, SamplingParamsBatch
 from repro.kernels.ops import (paged_attention, paged_prefill_attention,
                                paged_ragged_attention)
-from repro.kernels.sampling import batched_accept, batched_sample
+from repro.kernels.sampling import (batched_accept, batched_sample,
+                                    optional_stages)
 from repro.models import model
 from repro.models.attention import _project, _qk_norm
 from repro.models.layers import apply_rope, mlp, rmsnorm
@@ -199,6 +200,9 @@ class PagedModelRunner:
         #: token slots of the padded (B, C) buckets of those steps
         self.n_bucket_tokens = 0
         self.n_sampled_tokens = 0         # tokens sampled ON DEVICE
+        #: stochastic steps that ran each conditional sampler stage
+        #: (``kernels.sampling.optional_stages``)
+        self.sample_branch_steps = {"top_k": 0, "typical_p": 0}
         #: logit ROWS ([V] float vectors) pulled device→host — 0 on the
         #: fused engine path, where only sampled token ids cross back
         self.host_logit_rows = 0
@@ -938,6 +942,11 @@ class PagedModelRunner:
                 need_logprobs=sampling.need_logprobs,
                 use_counts=sampling.use_counts)
         self.n_sampled_tokens += S
+        if not sampling.all_greedy:
+            for stage, on in optional_stages(
+                    sampling.temperature, sampling.top_k,
+                    sampling.typical_p).items():
+                self.sample_branch_steps[stage] += int(on)
         return StepHandle(tokens=token, logprob=lp, top_ids=top_ids,
                           top_lps=top_lps, n_rows=S, runner=self,
                           emit=emit)
@@ -1206,6 +1215,7 @@ class PagedModelRunner:
                "ragged_steps": self.n_ragged_steps,
                "bucket_tokens": self.n_bucket_tokens,
                "sampled_tokens": self.n_sampled_tokens,
+               "sample_branch_steps": dict(self.sample_branch_steps),
                "host_logit_rows": self.host_logit_rows,
                "host_sync_bytes": self.host_sync_bytes,
                "host_block_s": self.t_block_s,

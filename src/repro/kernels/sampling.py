@@ -49,10 +49,50 @@ FILTERED = -3e37
 
 def unpack_bitmask(mask_bits: jax.Array, vocab: int) -> jax.Array:
     """Unpack ``uint32 [S, ceil(V/32)]`` grammar bitmasks (bit ``v%32``
-    of word ``v//32`` = token ``v`` allowed) into bool ``[S, V]``."""
-    idx = jnp.arange(vocab)
-    words = mask_bits[:, idx // 32]                        # [S, V]
-    return ((words >> (idx % 32).astype(jnp.uint32)) & 1).astype(bool)
+    of word ``v//32`` = token ``v`` allowed) into bool ``[S, V]`` by
+    shifts and a reshape, with no gather."""
+    bits = mask_bits[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)
+    return (bits & 1).astype(bool).reshape(mask_bits.shape[0], -1)[:, :vocab]
+
+
+def optional_stages(temperature, top_k, typical_p) -> dict:
+    """Whether a stochastic batch runs each conditional stage of
+    :func:`batched_sample`: top-k when some sampled row sets ``top_k >
+    0``, typical-p when some sampled row sets ``typical_p < 1``.  Works
+    on numpy arrays (the runner's ``sample_branch_steps`` counter) and
+    on traced ones (the ``lax.cond`` predicates) alike."""
+    live = temperature > 0
+    return {"top_k": (live & (top_k > 0)).any(),
+            "typical_p": (live & (typical_p < 1.0)).any()}
+
+
+def _prefix_keep(key, limit, mass=None):
+    """Keep set of a cumulative-mass cut, in the original token order.
+
+    Tokens are ranked by ``key`` ascending, ties by index (a stable
+    order); the cut keeps the longest prefix whose mass BEFORE each
+    token is below ``limit [S]`` (numpy searchsorted-left + 1, at least
+    one token).  One sort of ``(key, index[, mass])`` gives the prefix's
+    last token ``(kv, ki)``; a token is kept iff ``key < kv``, or ``key
+    == kv`` and its index is ``<= ki`` — exact, since the stable order
+    breaks ties by index, and no inverse permutation or ``[S, V]``
+    gather is needed.  ``mass`` rides the sort as a payload; left out,
+    it is ``-key`` (the top-p ranking) and the sort carries two operands.
+    Returns bool ``keep [S, V]``."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+    if mass is None:
+        skey, order = jax.lax.sort((key, idx), num_keys=1,
+                                   is_stable=True)
+        smass = -skey
+    else:
+        skey, order, smass = jax.lax.sort((key, idx, mass), num_keys=1,
+                                          is_stable=True)
+    before = jnp.cumsum(smass, axis=-1) - smass
+    n = jnp.sum(before < limit[:, None], axis=-1, keepdims=True)
+    last = jnp.maximum(n, 1) - 1                            # [S, 1]
+    kv = jnp.take_along_axis(skey, last, axis=-1)
+    ki = jnp.take_along_axis(order, last, axis=-1)
+    return (key < kv) | ((key == kv) & (idx <= ki))
 
 
 def _penalized(logits, bias, counts, freq_pen, pres_pen, rep_pen,
@@ -134,7 +174,12 @@ def batched_sample(logits, seeds, counters, temperature, top_k, top_p,
     placeholder-shaped, stage skipped), every row has ``temperature ==
     0`` (the sort/softmax/Gumbel stochastic pipeline is skipped), no
     row asked for logprobs (the ``[S, V]`` log-softmax is skipped and
-    the logprob outputs are zeros).
+    the logprob outputs are zeros).  The top-k and typical-p stages are
+    selected at RUN time instead (``lax.cond`` on
+    :func:`optional_stages`), so one program serves every batch and
+    their sorts run only when some sampled row asks: the always-on path
+    holds one full-vocabulary sort (top-p's) and no ``[S, V]`` gather
+    or scatter.
 
     The draw is Gumbel-max over the filtered distribution: ``argmax(x/T
     + g)`` samples exactly ``softmax(x/T)`` restricted to the surviving
@@ -155,34 +200,39 @@ def batched_sample(logits, seeds, counters, temperature, top_k, top_p,
     if all_greedy:
         token = greedy.astype(jnp.int32)
     else:
-        # temperature (guarded for the greedy rows), then top-k
+        # temperature (guarded for the greedy rows), then top-k.  Each
+        # filter below is a threshold compared in the original token
+        # order; the optional ones sit behind runtime conds, so their
+        # sorts run only on steps where some sampled row asks for them
+        stages = optional_stages(temperature, top_k, typical_p)
         t = jnp.where(temperature > 0, temperature, 1.0)
         z = x / t[:, None]
-        srt = jnp.sort(z, axis=-1)[:, ::-1]
-        kth = jnp.take_along_axis(
-            srt, jnp.clip(top_k - 1, 0, V - 1)[:, None], axis=-1)
-        z = jnp.where((top_k > 0)[:, None] & (z < kth), FILTERED, z)
 
-        # top-p over the softmax of the surviving support.  Keep rule
-        # matches numpy searchsorted-left + 1: token j (prob-desc
-        # order) survives iff the cumulative mass BEFORE it is < p.
-        m = jnp.max(z, axis=-1, keepdims=True)
-        e = jnp.exp(z - m)
-        p = e / jnp.sum(e, axis=-1, keepdims=True)
-        order = jnp.argsort(-p, axis=-1, stable=True)
-        sp = jnp.take_along_axis(p, order, axis=-1)
-        keep_sorted = (jnp.cumsum(sp, axis=-1) - sp) < top_p[:, None]
+        def _top_k(z):
+            # the k-th largest value, read from the ascending sort at
+            # V - k; ties at it all survive
+            srt = jnp.sort(z, axis=-1)
+            kth = jnp.take_along_axis(
+                srt, (V - jnp.clip(top_k, 1, V))[:, None], axis=-1)
+            return jnp.where((top_k > 0)[:, None] & (z < kth), FILTERED, z)
+
+        z = jax.lax.cond(stages["top_k"], _top_k, lambda z: z, z)
+
+        # top-p over the softmax of the surviving support: the cut is a
+        # prefix of the probability-descending stable order (token j
+        # survives iff the cumulative mass BEFORE it is < p);
         # top_p >= 1 disables the filter entirely (the host-oracle
         # semantics): float32 cumsum rounding must not cut a real tail
         # token
-        keep_sorted = keep_sorted | (top_p >= 1.0)[:, None]
-        # min-p on the SAME pre-filter probs (sorted space, sp[:, :1]
-        # is max(p)): token survives iff p >= min_p * max(p); min_p <= 0
-        # disables the filter
-        keep_sorted = keep_sorted & (
-            (sp >= min_p[:, None] * sp[:, :1]) | (min_p <= 0.0)[:, None])
-        inv = jnp.argsort(order, axis=-1, stable=True)
-        keep = jnp.take_along_axis(keep_sorted, inv, axis=-1)
+        m = jnp.max(z, axis=-1, keepdims=True)
+        e = jnp.exp(z - m)
+        p = e / jnp.sum(e, axis=-1, keepdims=True)
+        keep = _prefix_keep(-p, top_p) | (top_p >= 1.0)[:, None]
+        # min-p on the SAME pre-filter probs: token survives iff
+        # p >= min_p * max(p); min_p <= 0 disables the filter
+        pmax = jnp.max(p, axis=-1, keepdims=True)
+        keep = keep & ((p >= min_p[:, None] * pmax)
+                       | (min_p <= 0.0)[:, None])
 
         # typical-p (locally typical sampling) on the SAME pre-filter
         # probs: rank tokens by |surprisal − entropy| ascending and keep
@@ -190,23 +240,23 @@ def batched_sample(logits, seeds, counters, temperature, top_k, top_p,
         # searchsorted-left + 1 rule as top-p, in deviation order);
         # typical_p >= 1 disables, and the most-typical token always
         # survives its own filter (the host cutoff is max(1, ...))
-        surp = -jnp.log(jnp.where(p > 0, p, 1.0))
-        ent = jnp.sum(p * surp, axis=-1, keepdims=True)
-        dev = jnp.where(p > 0, jnp.abs(surp - ent), jnp.inf)
-        dorder = jnp.argsort(dev, axis=-1, stable=True)
-        dp = jnp.take_along_axis(p, dorder, axis=-1)
-        tkeep_sorted = ((jnp.cumsum(dp, axis=-1) - dp)
-                        < typical_p[:, None]) | (typical_p >= 1.0)[:, None]
-        tkeep_sorted = tkeep_sorted.at[:, 0].set(True)
-        dinv = jnp.argsort(dorder, axis=-1, stable=True)
-        keep = keep & jnp.take_along_axis(tkeep_sorted, dinv, axis=-1)
+        def _typical(p):
+            surp = -jnp.log(jnp.where(p > 0, p, 1.0))
+            ent = jnp.sum(p * surp, axis=-1, keepdims=True)
+            dev = jnp.where(p > 0, jnp.abs(surp - ent), jnp.inf)
+            return (_prefix_keep(dev, typical_p, p)
+                    | (typical_p >= 1.0)[:, None])
+
+        keep = keep & jax.lax.cond(
+            stages["typical_p"], _typical,
+            lambda p: jnp.ones(p.shape, bool), p)
 
         # the host keeps AT LEAST the max-probability token: top-p/min-p
         # keep it by construction (max(1, cutoff)), the typical filter
         # may not — a degenerate combination must degrade to top-1, not
         # filter everything
-        top1 = jnp.argmax(p, axis=-1)
-        keep = keep.at[jnp.arange(S), top1].set(True)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (S, V), 1)
+        keep = keep | (iota == jnp.argmax(p, axis=-1)[:, None])
         z = jnp.where(keep, z, FILTERED)
 
         # counter-based per-row keys: deterministic for a (seed,

@@ -429,3 +429,175 @@ def test_grammar_mask_respected_even_when_allowed_underflow():
     tokens, _, _, _ = _device(batch, logits)
     assert mask[int(tokens[0])]
     assert int(tokens[0]) == host
+
+
+# -- threshold-form filters: exactness at the cut -------------------------
+
+def _draws(samplers, logits, masks=None, n_rounds=1):
+    """Device and ref tokens for ``samplers`` (row i draws from
+    ``logits[i]``) over ``n_rounds`` successive counters."""
+    v = logits.shape[-1]
+    masks = masks or [None] * len(samplers)
+    specs = [(i, s, None if m is None else pack_token_bitmask(m))
+             for i, (s, m) in enumerate(zip(samplers, masks))]
+    dev, oracle = [], []
+    for r in range(n_rounds):
+        batch = SamplingParamsBatch.build(specs, v)
+        batch.counters[:] = r
+        dev.append(_device(batch, logits)[0])
+        oracle.append(ref.batched_sample_ref(
+            logits[batch.parent], batch.seeds, batch.counters,
+            batch.temperature, batch.top_k, batch.top_p, batch.min_p,
+            batch.typical_p, batch.freq_pen, batch.pres_pen,
+            batch.rep_pen, batch.bias, batch.counts, batch.mask_bits)[0])
+    return np.stack(dev), np.stack(oracle)
+
+
+def test_top_p_cut_inside_a_tie_group():
+    """Tokens of EQUAL probability on both sides of the top-p cut: the
+    stable order keeps the lower indices of the tie group, on device as
+    in the ref and the host dist."""
+    logits = np.full((1, V), -40.0, np.float32)
+    logits[0, 7] = 3.0
+    tied = [2, 9, 20, 30]                  # p ~ .405, then 4 x .149
+    logits[0, tied] = 2.0
+    for top_p, kept in ((0.5, {7, 2}), (0.6, {7, 2, 9}),
+                        (0.75, {7, 2, 9, 20})):
+        n = 128
+        samplers = [RequestSampler(temperature=1.0, top_p=top_p, seed=i)
+                    for i in range(n)]
+        assert set(np.flatnonzero(samplers[0].dist(logits[0]))) == kept
+        dev, oracle = _draws(samplers, np.tile(logits, (n, 1)))
+        assert np.array_equal(dev, oracle), top_p
+        assert set(int(t) for t in dev.ravel()) == kept, top_p
+
+
+def test_underflowed_tails_match_ref():
+    """Tails whose probability underflows to exactly 0 (ties at p = 0,
+    deviation inf): top-p just below 1 cuts into them, typical-p ranks
+    them last, min-p drops them — device ≡ ref token for token."""
+    rng = np.random.default_rng(29)
+    logits = np.full((S, V), -150.0, np.float32)
+    logits[:, :6] = rng.standard_normal((S, 6)).astype(np.float32)
+    below_one = float(np.nextafter(np.float32(1), np.float32(0)))
+    kw = [dict(top_p=below_one), dict(top_p=1.0), dict(typical_p=0.7),
+          dict(typical_p=below_one), dict(min_p=0.05),
+          dict(top_p=below_one, typical_p=0.9, min_p=0.01),
+          dict(top_k=V, top_p=0.9), dict(top_k=3, typical_p=0.5)]
+    samplers = [RequestSampler(temperature=0.7, seed=100 + i, **kw[i])
+                for i in range(S)]
+    dev, oracle = _draws(samplers, logits, n_rounds=16)
+    assert np.array_equal(dev, oracle)
+    assert (dev < 6).all()
+
+
+def test_top_k_ties_at_the_kth_value_all_survive():
+    """Ties AT the k-th value: every token equal to it survives (the
+    host's ``z < kth`` rule), on device as in the ref and the host."""
+    logits = np.full((1, V), -40.0, np.float32)
+    logits[0, 4] = 2.5
+    logits[0, [1, 12, 25]] = 1.5           # k = 2 cuts inside these ties
+    n = 128
+    samplers = [RequestSampler(temperature=1.5, top_k=2, seed=i)
+                for i in range(n)]
+    support = {4, 1, 12, 25}
+    assert set(np.flatnonzero(samplers[0].dist(logits[0]))) == support
+    dev, oracle = _draws(samplers, np.tile(logits, (n, 1)))
+    assert np.array_equal(dev, oracle)
+    assert set(int(t) for t in dev.ravel()) == support
+
+
+def test_mixed_optional_stages_in_one_batch():
+    """One batch whose rows mix top_k 0 / > 0, typical_p 1 / < 1 and
+    min_p 0 / > 0 (all eight combinations): device ≡ ref, and a row that
+    asks for no optional stage draws the same token whether or not its
+    neighbours switch the runtime conds on."""
+    rng = np.random.default_rng(31)
+    logits = (rng.standard_normal((S, V)) * 2).astype(np.float32)
+    combos = [(k, t, m) for k in (0, 6) for t in (1.0, 0.6)
+              for m in (0.0, 0.1)]
+    mk = lambda i, k, t, m: RequestSampler(  # noqa: E731
+        temperature=0.9, top_p=0.9, top_k=k, typical_p=t, min_p=m,
+        seed=1000 + i)
+    samplers = [mk(i, *c) for i, c in enumerate(combos)]
+    dev, oracle = _draws(samplers, logits, n_rounds=8)
+    assert np.array_equal(dev, oracle)
+    plain = [mk(i, 0, 1.0, m) for i, (_, _, m) in enumerate(combos)]
+    alone, _ = _draws(plain, logits, n_rounds=8)
+    assert np.array_equal(alone[:, 0], dev[:, 0])      # combos[0] is plain
+    assert np.array_equal(alone[:, 1], dev[:, 1])      # min-p only
+
+
+def test_grammar_row_beside_unmasked_rows_odd_vocab():
+    """A grammar-masked row (allowing tokens in both bitmask words)
+    beside unmasked rows at a vocabulary that is not a multiple of 32:
+    the shift-and-reshape unpack equals the word/bit definition, the
+    masked row draws only allowed tokens, and device ≡ ref."""
+    from repro.kernels.sampling import unpack_bitmask
+    v = 45
+    rng = np.random.default_rng(37)
+    bits = rng.random((4, v)) < 0.5
+    packed = np.stack([pack_token_bitmask(b) for b in bits])
+    np.testing.assert_array_equal(np.asarray(unpack_bitmask(packed, v)),
+                                  bits)
+    logits = (rng.standard_normal((4, v)) * 2).astype(np.float32)
+    mask = np.zeros(v, bool)
+    mask[[3, 31, 32, 40, 44]] = True
+    masks = [None, mask, None, None]
+    samplers = [RequestSampler(temperature=t, top_p=0.95, seed=50 + i)
+                for i, t in enumerate((0.8, 0.8, 0.0, 1.2))]
+    dev, oracle = _draws(samplers, logits, masks, n_rounds=16)
+    assert np.array_equal(dev, oracle)
+    assert all(mask[t] for t in dev[:, 1])
+    assert (dev < v).all()
+
+
+def test_prefix_keep_matches_the_sorted_oracle():
+    """The threshold form of a cumulative-mass cut keeps exactly the
+    stable-order prefix the permute-back form keeps, through heavy ties
+    in the key and zero-mass tails (dyadic masses: no rounding)."""
+    from repro.kernels.sampling import _prefix_keep
+    rng = np.random.default_rng(41)
+    rows, v = 16, 64
+    key = rng.integers(0, 6, (rows, v)).astype(np.float32)
+    mass = rng.integers(0, 4, (rows, v)).astype(np.float32) / 256
+    limit = rng.choice([0.0, 1 / 256, 0.1, 0.25, 0.5, 2.0], rows)
+    limit = limit.astype(np.float32)
+    want = np.zeros((rows, v), bool)
+    for s in range(rows):
+        order = np.argsort(key[s], kind="stable")
+        before = np.cumsum(mass[s][order]) - mass[s][order]
+        n = max(int((before < limit[s]).sum()), 1)
+        want[s, order[:n]] = True
+    np.testing.assert_array_equal(
+        np.asarray(_prefix_keep(key, limit, mass)), want)
+
+
+def test_sample_branch_steps_counts_conditional_stages():
+    """``stats()["runner"]["sample_branch_steps"]`` counts the
+    stochastic steps that ran each runtime-conditional stage: a top-k
+    request counts top_k only, a typical-p request typical_p only, and a
+    greedy request nothing, whatever it sets."""
+    from repro.configs import get_config
+    from repro.core import ChatCompletionRequest, ChatMessage, MLCEngine
+    eng = MLCEngine()
+    eng.load_model("m", get_config("llama-3.1-8b", reduced=True),
+                   max_slots=2, max_context=96, seed=0,
+                   backend="paged", page_size=8)
+
+    def run(**kw):
+        eng.chat_completions_create(ChatCompletionRequest(
+            messages=[ChatMessage("user", "hi")], model="m",
+            max_tokens=3, seed=1, **kw))
+        return dict(eng.stats("m")["runner"]["sample_branch_steps"])
+
+    try:
+        assert run(temperature=0.9, top_p=0.9) == {"top_k": 0,
+                                                   "typical_p": 0}
+        a = run(temperature=0.9, top_k=5)
+        assert a["top_k"] >= 1 and a["typical_p"] == 0, a
+        b = run(temperature=0.9, typical_p=0.8)
+        assert b["top_k"] == a["top_k"] and b["typical_p"] >= 1, b
+        assert run(temperature=0.0, top_k=5, typical_p=0.5) == b
+    finally:
+        eng.shutdown()

@@ -10,7 +10,9 @@ the test worker that runs this file loads the TPU library.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,7 @@ from repro.configs import get_config
 from repro.kernels.paged_attention import (head_block, paged_attention,
                                            paged_prefill_attention,
                                            paged_ragged_attention)
+from repro.kernels.sampling import batched_sample
 from repro.kernels.w4a16_gemm import w4a16_gemm
 
 CFG = get_config("phi-3.5-mini")
@@ -153,3 +156,66 @@ def test_w4a16_gemm_lowers(one_chip):
              _spec(one_chip, (M, K), jnp.bfloat16),
              _spec(one_chip, (K // 2, N), jnp.int8),
              _spec(one_chip, (K // G, N), jnp.bfloat16))
+
+
+def _hlo_outside_conds(text: str) -> list:
+    """Instruction lines of an HLO module that run on every call: those
+    of computations reachable from the entry without entering a
+    ``conditional``'s branches."""
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            words = line.split()
+            cur = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
+            entry = cur if words[0] == "ENTRY" else entry
+            comps[cur] = []
+        elif cur and line.startswith("  "):
+            comps[cur].append(line)
+    callee = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+    seen, todo, out = set(), [entry], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        out += comps[name]
+        for line in comps[name]:
+            todo += callee.findall(line)
+    return out
+
+
+def _out_elems(line: str, op: str) -> int:
+    """Elements of the largest array an HLO instruction outputs."""
+    shape = line.split(" = ", 1)[1].split(f" {op}(", 1)[0]
+    return max((math.prod(int(d) for d in dims.split(",") if d)
+                for dims in re.findall(r"\[([\d,]*)\]", shape)), default=0)
+
+
+def test_sampler_sorts_once_and_gathers_no_vocab_plane(one_chip):
+    """The stochastic sampler at the benchmark cells' shape (16 rows of
+    phi-3.5-mini's 32064-token vocabulary, no planes, no logprobs) holds
+    at most one full-vocabulary sort outside its runtime-conditional
+    stages, and no gather or scatter whose output is a whole ``[S, V]``
+    plane anywhere."""
+    S, V = 16, CFG.vocab_size
+    f32, i32, u32 = jnp.float32, jnp.int32, jnp.uint32
+    row = lambda dt: _spec(one_chip, (S,), dt)  # noqa: E731
+    args = [_spec(one_chip, (S, V), jnp.bfloat16), row(u32), row(i32),
+            row(f32), row(i32), row(f32), row(f32), row(f32), row(f32),
+            row(f32), row(f32), _spec(one_chip, (S, 1), f32),
+            _spec(one_chip, (S, 1), f32),
+            _spec(one_chip, (S, -(-V // 32)), u32)]
+    text = jax.jit(lambda *a: batched_sample(
+        *a, n_top=0, use_planes=False, all_greedy=False,
+        need_logprobs=False)).lower(*args).compile().as_text()
+    ins = [line for line in text.splitlines() if " = " in line]
+    sorts = [line for line in ins if " sort(" in line
+             and _out_elems(line, "sort") >= S * V]
+    assert sorts, "no full-vocabulary sort found: the HLO parse is stale"
+    always = [line for line in _hlo_outside_conds(text) if " sort(" in line
+              and _out_elems(line, "sort") >= S * V]
+    assert len(always) <= 1, always
+    planes = [line.strip()[:160] for line in ins for op in ("gather",
+                                                             "scatter")
+              if f" {op}(" in line and _out_elems(line, op) >= S * V]
+    assert not planes, planes
