@@ -22,7 +22,7 @@ for doc in README.md docs/ARCHITECTURE.md; do
 done
 # ... and every repo-relative file reference inside them must resolve
 # (paths containing a directory separator, e.g. src/repro/core/engine.py,
-# benchmarks/run.py — bare names like ops.py are not checked, and URLs
+# bench/run.py — bare names like ops.py are not checked, and URLs
 # are stripped first so external links never trip the gate)
 missing=0
 for doc in README.md docs/ARCHITECTURE.md; do

@@ -225,8 +225,6 @@ class _LoadedModel:
     inflight: Optional[_Inflight] = None      # the undrained step
     next_plan: object = None          # depth-2: plan built behind device
     inflight_max: int = 0             # max concurrent steps observed
-    gap_s: float = 0.0                # device idle between dispatches
-    t_last_ready: float = 0.0         # monotonic stamp of last drain
     host_s: float = 0.0               # host time not hidden by device
     # -- speculative decoding (loop-thread confined counters) --------
     speculation: str = "off"          # "off" | "prompt_lookup"
@@ -967,11 +965,6 @@ class MLCEngine:
                 self._evict_request(lm, r, publish=False)
                 self._fail(r, e)
             return True
-        now = time.monotonic()
-        if prev is None and lm.t_last_ready > 0.0:
-            # nothing was in flight while the host planned this step:
-            # that whole span was device idle (the depth-1 cost)
-            lm.gap_s += max(0.0, now - lm.t_last_ready)
         lm.exec_steps += 1       # before token consumption wakes callers:
         #                          stats() must never see calls > steps
         depth = (1 if prev is not None else 0) + 1
@@ -1072,7 +1065,6 @@ class MLCEngine:
                     seq.inflight_src = None
                 self._maybe_release(lm, seq)
             return
-        lm.t_last_ready = time.monotonic()
         sampled = {}    # id(consumer seq) -> its sample rows, in order
         for i, s in enumerate(h.consumers):
             sampled.setdefault(id(s), []).append(
@@ -1837,7 +1829,7 @@ class MLCEngine:
             {"backend": "paged" | "dense",
              "engine":    {"exec_steps": ...,    # steps that dispatched work
                            "pipeline_depth": ..., "inflight_steps": ...,
-                           "dispatch_gap_ms": ..., "host_ms_per_step": ...,
+                           "host_ms_per_step": ...,
                            "speculation": ..., "draft_k": ...,
                            "drafted": ..., "accepted": ...,
                            "accept_rate": ...},
@@ -1863,8 +1855,6 @@ class MLCEngine:
                     "exec_steps": lm.exec_steps,
                     "pipeline_depth": lm.pipeline_depth,
                     "inflight_steps": lm.inflight_max,
-                    "dispatch_gap_ms": round(
-                        1000.0 * lm.gap_s / max(1, lm.exec_steps), 3),
                     "host_ms_per_step": round(
                         1000.0 * lm.host_s / max(1, lm.exec_steps), 3),
                     "speculation": lm.speculation,

@@ -9,32 +9,28 @@ relayout copy around the scatter or the kernel.  EVERY token
 — prompt or completion, cold or cache-hit — flows through the same paged
 machinery, and a whole engine step dispatches as ONE kernel call:
 
-* ``run_step(rows)``: the fused ragged step.  Each row is a chunk of
-  consecutive tokens of one sequence — a decode token is a length-1 row,
-  a prefill chunk up to ``chunk_size`` (or more, budget permitting)
-  tokens.  All rows' K/V are scattered into their sequences' pages and
-  attention runs via the multi-sequence ``kernels.paged_ragged_attention``
-  kernel (per-row causal masks against each sequence's own cursor) in
-  one jitted step.  Rows are padded to a (B, C) bucket so the jit
-  variant count stays bounded; pad K/V writes land in a dedicated trash
-  page.  This is what collapses the former one-kernel-call-per-sequence
-  dispatch into one call per engine step.
-* ``prefill_chunk(sid, tokens)`` / ``decode(seq_tokens)``: the per-kind
-  single calls (one sequence's chunk / one batched decode token per
-  sequence) — kept as the reference path for tests and non-interleaving
-  callers; ``run_step`` subsumes both on the engine path.
+``run_step(rows)`` is the runner's one execution path: the fused ragged
+step.  Each row is a chunk of consecutive tokens of one sequence — a
+decode token is a length-1 row, a prefill chunk up to ``chunk_size`` (or
+more, budget permitting) tokens.  All rows' K/V are scattered into their
+sequences' pages and attention runs via the multi-sequence
+``kernels.paged_ragged_attention`` kernel (per-row causal masks against
+each sequence's own cursor) in one jitted step.  Rows are padded to a
+(B, C) bucket so the jit variant count stays bounded; pad K/V writes
+land in a dedicated trash page.
 
-There is no dense-prefill-then-scatter path anymore and no decode-per-
-suffix-token replay: ``begin_seq`` adopts the longest prefix already in
-the :class:`repro.core.prefix_cache.PrefixCache` (sharing full pages
+There is no dense-prefill-then-scatter path and no decode-per-suffix-
+token replay: ``begin_seq`` adopts the longest prefix already in the
+:class:`repro.core.prefix_cache.PrefixCache` (sharing full pages
 zero-copy, forking a partial tail page copy-on-write) and the uncached
-suffix runs through ragged rows / ``prefill_chunk``.  ``prefill_seq`` is
-a thin loop over chunks for callers that want the whole prompt at once.
+suffix runs through ragged rows.  ``prefill_chunk(sid, tokens)``,
+``decode(seq_tokens)`` and ``prefill_seq(prompt_ids)`` are host helpers
+that build rows for ``run_step`` and return per-sequence logits, for
+tests and callers that do not interleave.
 
 Page bookkeeping lives in :class:`repro.core.paged_cache.PageManager`.
-:class:`PagedEngineBackend` wraps the runner in the slot-keyed unified
-runner interface ``MLCEngine`` drives, adding the chunked-prefill calls
-(``begin_prefill``/``run_step``) the step-plan scheduler uses.
+:class:`PagedEngineBackend` wraps the runner in the slot-keyed calls
+``MLCEngine`` drives (``begin_prefill``/``run_step``/``release``).
 """
 from __future__ import annotations
 
@@ -52,8 +48,7 @@ from repro.configs.base import ModelConfig
 from repro.core.paged_cache import OutOfPages, PageManager
 from repro.core.prefix_cache import PrefixCache
 from repro.core.sampler import SampleResult, SamplingParamsBatch
-from repro.kernels.ops import (paged_attention, paged_prefill_attention,
-                               paged_ragged_attention)
+from repro.kernels.ops import paged_ragged_attention
 from repro.kernels.sampling import (batched_accept, batched_sample,
                                     optional_stages)
 from repro.models import model
@@ -192,9 +187,7 @@ class PagedModelRunner:
         self.last_prefill_info: Dict[str, int] = {"prefix_cached_tokens": 0}
         self.n_prefills = 0               # prompt prefills (not forks)
         self.n_forks = 0                  # CoW sequence forks
-        self.n_prefill_chunks = 0         # chunked prefill kernel steps
         self.n_prefill_tokens = 0         # real (non-pad) tokens prefilled
-        self.n_decode_steps = 0           # batched decode steps
         self.n_decode_tokens = 0          # tokens decoded across the batch
         self.n_ragged_steps = 0           # fused ragged kernel steps
         #: token slots of the padded (B, C) buckets of those steps
@@ -235,7 +228,6 @@ class PagedModelRunner:
         self._staging = ({}, {})
         self._staging_i = 0
         #: bounded trace of jitted steps, for liveness assertions/tests:
-        #: ("decode", batch_size) | ("chunk", n_valid_tokens) |
         #: ("ragged", n_decode_rows, n_prefill_tokens)
         self.step_log: Deque[Tuple] = deque(maxlen=4096)
         if params is None:
@@ -265,9 +257,6 @@ class PagedModelRunner:
                          for _ in range(L)]
         self.v_scales = [jnp.zeros(scale_shape, jnp.bfloat16)
                          for _ in range(L)]
-        self._step = jax.jit(self._decode_step, donate_argnums=(1, 2, 3, 4))
-        self._chunk_step = jax.jit(self._prefill_chunk_step,
-                                   donate_argnums=(1, 2, 3, 4))
         # one jit object: variants are cached per traced (B, C) bucket;
         # run_step pads both to powers of two so the count stays bounded
         # at O(log(max_slots) * log(max chunk tokens))
@@ -305,18 +294,6 @@ class PagedModelRunner:
         self._zero_prev: Dict[int, object] = {}
 
     # ------------------------------------------------------------------
-    def _layer_params(self):
-        """Unstack the scanned block params into per-layer trees."""
-        g = self.cfg.grouped_pattern()
-        layers = list(self.params["decoder"]["prefix"])
-        if g.n_blocks:
-            stacked = self.params["decoder"]["blocks"]
-            for i in range(g.n_blocks):
-                for j in range(len(g.block)):
-                    layers.append(jax.tree.map(lambda x: x[i], stacked[j]))
-        layers += list(self.params["decoder"]["suffix"])
-        return layers
-
     @staticmethod
     def _page_quant(x):
         """Symmetric per-(token, kv-head) int8 quantization of K/V rows:
@@ -377,79 +354,6 @@ class PagedModelRunner:
         kp[li], vp[li] = put(kp[li], x=k), put(vp[li], x=v)
         return kp[li], vp[li], None, None
 
-    def _decode_step(self, params, k_pages, v_pages, k_scales, v_scales,
-                     token, pos, page_table, lens, page_idx, page_off):
-        """token [B,1], pos [B], page_table [B,pps], lens [B] (incl. the
-        new token), page_idx/page_off [B]: physical write location."""
-        cfg = self.cfg
-        B = token.shape[0]
-        x = jnp.take(params["embed"], token, axis=0)           # [B,1,D]
-        layers = self._layer_params_traced(params)
-        pools = tuple(map(list, (k_pages, v_pages, k_scales, v_scales)))
-        for li, p in enumerate(layers):
-            h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
-            q = _project(cfg, p["attn"], h, "q", cfg.n_heads)  # [B,1,H,Dh]
-            k = _project(cfg, p["attn"], h, "k", cfg.n_kv_heads)
-            v = _project(cfg, p["attn"], h, "v", cfg.n_kv_heads)
-            q, k = _qk_norm(cfg, p["attn"], q, k)
-            q = apply_rope(q, pos[:, None], cfg.rope_theta)
-            k = apply_rope(k, pos[:, None], cfg.rope_theta)
-            # scatter the new K/V into each sequence's current page
-            kp, vp, ks, vs = self._scatter_kv(pools, li, page_idx,
-                                              page_off, k[:, 0], v[:, 0])
-            att = paged_attention(q[:, 0], kp, vp, page_table, lens,
-                                  k_scales=ks, v_scales=vs)   # [B,H,Dh]
-            y = qdot(att.reshape(B, 1, -1), p["attn"]["wo"])
-            x = x + y
-            h = rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
-            x = x + mlp(h, p["ffn"], cfg.act)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"].T
-        else:
-            logits = x @ params["lm_head"]
-        return (logits,) + pools
-
-    def _prefill_chunk_step(self, params, k_pages, v_pages, k_scales,
-                            v_scales, tokens, pos, page_table, ctx, start,
-                            page_idx, page_off):
-        """One chunked-prefill step for a single sequence.
-
-        tokens/pos/page_idx/page_off [C] (C = chunk_size, padded);
-        page_table [pps]; ctx scalar (tokens in pages incl. this chunk's
-        valid suffix); start scalar (global position of chunk row 0).
-        K/V for all C rows are scattered into pages (pad rows land in
-        the trash page) and the chunk attends to the page table with
-        causal masking inside the chunk.  Returns logits [C, V]."""
-        cfg = self.cfg
-        C = tokens.shape[0]
-        x = jnp.take(params["embed"], tokens[None], axis=0)    # [1,C,D]
-        layers = self._layer_params_traced(params)
-        pools = tuple(map(list, (k_pages, v_pages, k_scales, v_scales)))
-        for li, p in enumerate(layers):
-            h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
-            q = _project(cfg, p["attn"], h, "q", cfg.n_heads)  # [1,C,H,Dh]
-            k = _project(cfg, p["attn"], h, "k", cfg.n_kv_heads)
-            v = _project(cfg, p["attn"], h, "v", cfg.n_kv_heads)
-            q, k = _qk_norm(cfg, p["attn"], q, k)
-            q = apply_rope(q, pos[None, :], cfg.rope_theta)
-            k = apply_rope(k, pos[None, :], cfg.rope_theta)
-            kp, vp, ks, vs = self._scatter_kv(pools, li, page_idx,
-                                              page_off, k[0], v[0])
-            att = paged_prefill_attention(q[0], kp, vp, page_table, ctx,
-                                          start, k_scales=ks,
-                                          v_scales=vs)         # [C,H,Dh]
-            y = qdot(att.reshape(1, C, -1), p["attn"]["wo"])
-            x = x + y
-            h = rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
-            x = x + mlp(h, p["ffn"], cfg.act)
-        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"].T
-        else:
-            logits = x @ params["lm_head"]
-        return (logits[0],) + pools
-
     def _ragged_logits(self, params, k_pages, v_pages, k_scales, v_scales,
                        tokens, pos, page_tables, contexts, starts, lengths,
                        page_idx, page_off):
@@ -501,8 +405,8 @@ class PagedModelRunner:
     def _ragged_step(self, params, k_pages, v_pages, k_scales, v_scales,
                      tokens, pos, page_tables, contexts, starts, lengths,
                      page_idx, page_off):
-        """Legacy logits-path reduce over :meth:`_ragged_logits`: each
-        row's last-valid-slot logits [B, V]."""
+        """Logits-path reduce over :meth:`_ragged_logits` (``run_step``
+        without sampling): each row's last-valid-slot logits [B, V]."""
         logits, k_pages, v_pages, k_scales, v_scales = self._ragged_logits(
             params, k_pages, v_pages, k_scales, v_scales, tokens, pos,
             page_tables, contexts, starts, lengths, page_idx, page_off)
@@ -632,50 +536,15 @@ class PagedModelRunner:
         return self.pm.seqs[sid].length
 
     def prefill_chunk(self, sid: int, tokens: List[int]) -> np.ndarray:
-        """Prefill up to ``chunk_size`` consecutive prompt tokens.
-
-        K/V for every token are scattered into the sequence's pages and
-        the chunk attends to the full page table (causal inside the
-        chunk) in ONE jitted step; a partial final chunk is padded to
-        ``chunk_size`` (pad rows write to the trash page).  Raises
-        :class:`OutOfPages` *before* mutating sequence state when the
-        pool cannot back the chunk.  Returns the last valid token's
-        logits [V]."""
-        tokens = [int(t) for t in tokens]
-        T = len(tokens)
-        C = self.chunk_size
-        assert 0 < T <= C, (T, C)
-        alloc = self.pm.seqs[sid]
-        start = alloc.length
-        need_pages = -(-(start + T) // self.page_size)
-        if need_pages > self.pm.pages_per_seq:
-            raise OutOfPages(f"seq {sid} at pages_per_seq cap")
-        self.pm.require_pages(max(0, need_pages - len(alloc.pages)))
-        self.pm.append_tokens(sid, T)
-        pages = alloc.pages
-        pos = (start + np.arange(C)).astype(np.int32)
-        page_idx = np.full(C, self.trash_page, np.int32)
-        page_idx[:T] = [pages[p // self.page_size] for p in pos[:T]]
-        page_off = (pos % self.page_size).astype(np.int32)
-        tok = np.zeros(C, np.int32)
-        tok[:T] = tokens
-        table = self.pm.page_table([sid])[0]
-        logits, self.k_pages, self.v_pages, self.k_scales, self.v_scales = \
-            self._chunk_step(
-                self.params, self.k_pages, self.v_pages, self.k_scales,
-                self.v_scales, jnp.asarray(tok),
-                jnp.asarray(pos), jnp.asarray(table), np.int32(start + T),
-                np.int32(start), jnp.asarray(page_idx),
-                jnp.asarray(page_off))
-        self.seq_tokens[sid].extend(tokens)
-        self.n_prefill_chunks += 1
-        self.n_prefill_tokens += T
-        self.step_log.append(("chunk", T))
-        out = np.asarray(logits[T - 1].astype(jnp.float32))
-        self.host_logit_rows += 1
-        self.host_sync_bytes += out.nbytes
-        self._last_logits_np = out
-        return out
+        """Prefill up to ``chunk_size`` consecutive prompt tokens as one
+        ``run_step`` row.  Raises :class:`OutOfPages` *before* mutating
+        sequence state when the pool cannot back the chunk.  Returns the
+        last valid token's logits [V]."""
+        assert 0 < len(tokens) <= self.chunk_size, \
+            (len(tokens), self.chunk_size)
+        self._last_logits_np = self.run_step(
+            [(sid, list(tokens), "prefill")])[sid]
+        return self._last_logits_np
 
     def prefill_seq(self, prompt_ids: List[int]) -> int:
         """Prefill a whole prompt: ``begin_seq`` (prefix-cache adoption)
@@ -736,8 +605,9 @@ class PagedModelRunner:
         attention inside the SAME jitted call and a
         :class:`SampleResult` (token ids + logprobs, ordered like the
         batch) returns — ``[B, V]`` logits never cross the device→host
-        boundary.  Without it (the legacy/test path) each row's
-        last-valid-token logits return as ``{sid: [V] float32}``,
+        boundary.  Without it (the ``decode``/``prefill_chunk`` helpers
+        and tests) each row's last-valid-token logits return as ``{sid:
+        [V] float32}``,
         counted by ``host_logit_rows`` — unless ``return_logits=False``
         (a step that only advances mid-prompt prefill produces no token
         and must transfer nothing).
@@ -989,47 +859,10 @@ class PagedModelRunner:
         return self._last_logits_np
 
     def decode(self, seq_tokens: Dict[int, int]) -> Dict[int, np.ndarray]:
-        """One batched decode step for {seq_id: token}."""
-        sids = sorted(seq_tokens)
-        B = len(sids)
-        # capacity pre-check: fail *before* touching any sequence state so
-        # the engine can preempt and retry without corrupted bookkeeping
-        growing = sum(1 for s in sids
-                      if self.pm.seqs[s].length % self.page_size == 0
-                      and self.pm.seqs[s].length // self.page_size
-                      == len(self.pm.seqs[s].pages))
-        self.pm.require_pages(growing)
-        for s in sids:
-            if -(-(self.pm.seqs[s].length + 1) // self.page_size) \
-                    > self.pm.pages_per_seq:
-                raise OutOfPages(f"seq {s} at pages_per_seq cap")
-        pos = self.pm.context_lens(sids)               # write position
-        for sid in sids:
-            self.pm.append_tokens(sid, 1)
-        table = self.pm.page_table(sids)
-        lens = self.pm.context_lens(sids)              # now includes new tok
-        page_idx = np.array(
-            [self.pm.seqs[s].pages[p // self.page_size]
-             for s, p in zip(sids, pos)], np.int32)
-        page_off = (pos % self.page_size).astype(np.int32)
-        tok = np.array([[seq_tokens[s]] for s in sids], np.int32)
-        logits, self.k_pages, self.v_pages, self.k_scales, self.v_scales = \
-            self._step(
-                self.params, self.k_pages, self.v_pages, self.k_scales,
-                self.v_scales, jnp.asarray(tok),
-                jnp.asarray(pos.astype(np.int32)), jnp.asarray(table),
-                jnp.asarray(lens), jnp.asarray(page_idx),
-                jnp.asarray(page_off))
-        for s in sids:
-            if s in self.seq_tokens:
-                self.seq_tokens[s].append(int(seq_tokens[s]))
-        self.n_decode_steps += 1
-        self.n_decode_tokens += B
-        self.step_log.append(("decode", B))
-        out = np.asarray(logits[:, 0].astype(jnp.float32))
-        self.host_logit_rows += B
-        self.host_sync_bytes += out.nbytes
-        return {s: out[i] for i, s in enumerate(sids)}
+        """One batched decode step for {seq_id: token}: a length-1
+        ``run_step`` row per sequence.  Returns {seq_id: logits [V]}."""
+        return self.run_step([(sid, [int(seq_tokens[sid])], "decode")
+                              for sid in sorted(seq_tokens)])
 
     def rewind_tokens(self, sid: int, n: int = 1):
         """Un-append the last ``n`` tokens of a live sequence.  Lag-1
@@ -1196,11 +1029,8 @@ class PagedModelRunner:
 
     def stats(self) -> dict:
         """Runner counters.  ``attn_kernel_calls`` is the total number of
-        attention dispatches (fused ragged steps + legacy per-sequence
-        chunk and per-batch decode calls) — the engine path issues
-        exactly one per step, so ``attn_kernel_calls / engine exec
-        steps`` should be 1.0 (surfaced by the mixed-traffic benchmark
-        as ``kernel_calls_per_step``)."""
+        attention dispatches — one per fused ragged step, so on the
+        engine path ``attn_kernel_calls / engine exec steps`` is 1.0."""
         out = {"pages": self.pm.stats(),
                "kv_dtype": self.kv_dtype,
                "weight_quant": self.weight_quant,
@@ -1208,9 +1038,7 @@ class PagedModelRunner:
                "prefills": self.n_prefills,
                "forks": self.n_forks,
                "chunk_size": self.chunk_size,
-               "prefill_chunks": self.n_prefill_chunks,
                "prefill_tokens": self.n_prefill_tokens,
-               "decode_steps": self.n_decode_steps,
                "decode_tokens": self.n_decode_tokens,
                "ragged_steps": self.n_ragged_steps,
                "bucket_tokens": self.n_bucket_tokens,
@@ -1222,30 +1050,24 @@ class PagedModelRunner:
                "jit_buckets": len(self._seen_buckets),
                "warmup_compiles": self.n_warmup_compiles,
                "rewinds": self.n_rewinds,
-               "attn_kernel_calls": (self.n_ragged_steps
-                                     + self.n_prefill_chunks
-                                     + self.n_decode_steps)}
+               "attn_kernel_calls": self.n_ragged_steps}
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()
         return out
 
 
 class PagedEngineBackend:
-    """Slot-keyed unified-runner facade over :class:`PagedModelRunner`.
+    """Slot-keyed facade over :class:`PagedModelRunner` for ``MLCEngine``.
 
-    ``MLCEngine`` drives every backend through the same calls —
-    ``prefill(slot, ids)``, ``decode(tokens_by_slot, pos_by_slot)``,
-    ``release(slot)``, ``stats()`` — so the scheduler/engine code is
-    backend-agnostic.  The paged backend additionally supports CHUNKED
-    prefill (``supports_chunked_prefill``): ``begin_prefill(slot, ids)``
-    opens the sequence and adopts the prefix-cache hit, then the engine
-    streams the uncached suffix through ragged step rows across as many
-    scheduler steps as the token budget allows — and FUSED execution
-    (``supports_ragged_step``): ``run_step(rows)`` dispatches a whole
-    step plan (every decode token + every prefill chunk) as one ragged
-    attention kernel call.  This facade maps engine slots onto paged
-    seq_ids, publishes finished (and preempted-mid-prefill) sequences
-    into the prefix cache, and frees aborted ones without publishing.
+    The engine opens a slot with ``begin_prefill(slot, ids)``, which
+    adopts the prefix-cache hit (``supports_chunked_prefill``), then
+    streams the uncached suffix and every decode token through
+    ``run_step(rows)`` (``supports_ragged_step``): a whole step plan
+    dispatched as one ragged attention kernel call, the runner's one
+    execution path.  ``release(slot)`` ends it.  This facade maps engine
+    slots onto paged seq_ids, publishes finished (and preempted-mid-
+    prefill) sequences into the prefix cache, and frees aborted ones
+    without publishing.
     """
 
     supports_chunked_prefill = True
@@ -1281,16 +1103,6 @@ class PagedEngineBackend:
     def last_prefill_info(self) -> Dict[str, int]:
         return self.runner.last_prefill_info
 
-    def prefill(self, slot: int, prompt_ids: List[int],
-                embeds: Optional[np.ndarray] = None) -> np.ndarray:
-        """Whole-prompt prefill (a loop of chunks) — kept for callers
-        that don't interleave; the engine uses the chunked calls."""
-        assert embeds is None, "paged backend: vision embeds unsupported"
-        assert slot not in self._slot_seq, f"slot {slot} already bound"
-        sid = self.runner.prefill_seq(prompt_ids)
-        self._slot_seq[slot] = sid
-        return self.runner.last_prefill_logits()
-
     def begin_prefill(self, slot: int, prompt_ids: List[int]) -> int:
         """Open ``slot`` for chunked prefill; adopts the longest cached
         prefix and returns how many leading tokens are already in pages
@@ -1299,11 +1111,6 @@ class PagedEngineBackend:
         sid = self.runner.begin_seq(prompt_ids)
         self._slot_seq[slot] = sid
         return self.runner.seq_len(sid)
-
-    def prefill_chunk(self, slot: int, tokens: List[int]) -> np.ndarray:
-        """Append one chunk of prompt tokens to ``slot``'s sequence;
-        returns the last token's logits."""
-        return self.runner.prefill_chunk(self._slot_seq[slot], tokens)
 
     def run_step(self, rows: List[Tuple[int, List[int], str]],
                  sampling: Optional[SamplingParamsBatch] = None,
@@ -1319,8 +1126,8 @@ class PagedEngineBackend:
         pipelined engine path; ``prev``/``decode_srcs`` feed decode
         tokens device-to-device from the previous handle, keyed by row
         index, which is invariant under the slot→seq mapping).
-        Otherwise per-slot last-valid-token logits return (the
-        legacy/test path) — or nothing at all with
+        Otherwise per-slot last-valid-token logits return (the tests'
+        path) — or nothing at all with
         ``return_logits=False``.  Raises :class:`OutOfPages` before any
         state mutates when the pool cannot back the whole step."""
         out = self.runner.run_step(
@@ -1358,13 +1165,6 @@ class PagedEngineBackend:
             f"slot {dst_slot} already bound"
         self._slot_seq[dst_slot] = self.runner.fork_seq(
             self._slot_seq[src_slot])
-
-    def decode(self, tokens_by_slot: Dict[int, int],
-               pos_by_slot: Dict[int, int]) -> Dict[int, np.ndarray]:
-        del pos_by_slot                    # positions tracked by PageManager
-        seq_tok = {self._slot_seq[s]: t for s, t in tokens_by_slot.items()}
-        out = self.runner.decode(seq_tok)
-        return {s: out[self._slot_seq[s]] for s in tokens_by_slot}
 
     def release(self, slot: int, publish: bool = True):
         sid = self._slot_seq.pop(slot, None)

@@ -82,7 +82,7 @@ def batched_sample(logits, seeds, counters, temperature, top_k, top_p,
     counter-based Gumbel-max draw, optional top-``n_top`` logprobs
     gather).  The engine path chains the same function INSIDE the fused
     ragged step jit (``PagedModelRunner.run_step``) so sampling adds no
-    dispatch; this standalone wrapper serves tests and benchmarks.  Jit
+    dispatch; this standalone wrapper serves tests.  Jit
     variants are keyed by ``(S, V, n_top)`` — callers bucket S."""
     return _batched_sample(logits, seeds, counters, temperature, top_k,
                            top_p, min_p, typical_p, freq_pen, pres_pen,

@@ -1,8 +1,8 @@
 """Persistent compilation cache for the entry points.
 
-Entry points (``chip_smoke.py``, ``repro.launch.serve``,
-``benchmarks.run``) call :func:`use_repo_compile_cache` once at start-up;
-library modules and tests never do.  Where ``JAX_COMPILATION_CACHE_DIR``
+Entry points (``chip_smoke.py``, ``repro.launch.serve``) call
+:func:`use_repo_compile_cache` once at start-up; library modules and
+tests never do.  Where ``JAX_COMPILATION_CACHE_DIR``
 is set, JAX reads it itself and nothing is set here.  Otherwise, on a
 TPU, the cache lives at the fixed ``<repo>/.jax_cache`` (git-ignored):
 the path is part of a cache entry's key, so it is never derived from a

@@ -51,7 +51,7 @@ def test_chunk_logits_match_dense_per_chunk(setup):
         done += n
         errs.append(float(np.max(np.abs(logits - full[done - 1]))))
     assert max(errs) < 0.06, errs
-    assert pr.n_prefill_chunks == 3
+    assert pr.n_ragged_steps == 3
     assert pr.n_prefill_tokens == T
     pr.free(sid)
     assert pr.pm.num_free_pages == 32          # trash page not leased
@@ -183,7 +183,7 @@ def test_run_step_matches_per_sequence_path(setup):
                           enable_prefix_cache=False)
     sa = pr.begin_seq(ids_a)
     sb = pr.prefill_seq(ids_b[:10])
-    base_calls = pr.n_prefill_chunks + pr.n_decode_steps
+    base_steps = pr.n_ragged_steps
     # fused: A prefills 9 tokens, B decodes — ONE ragged step
     out = pr.run_step([(sa, ids_a[:9], "prefill"), (sb, [ids_b[10]],
                                                     "decode")])
@@ -194,8 +194,7 @@ def test_run_step_matches_per_sequence_path(setup):
                                                     "decode")])
     assert float(np.max(np.abs(out[sa] - full_a[T_a - 1]))) < 0.06
     assert float(np.max(np.abs(out[sb] - full_b[11]))) < 0.06
-    assert pr.n_ragged_steps == 2              # and nothing else dispatched
-    assert pr.n_prefill_chunks + pr.n_decode_steps == base_calls
+    assert pr.n_ragged_steps == base_steps + 2  # nothing else dispatched
     assert list(pr.step_log)[-2:] == [("ragged", 1, 9), ("ragged", 1, 16)]
     pr.free(sa), pr.free(sb)
     assert pr.pm.num_free_pages == 32          # pads stayed in trash page
@@ -210,6 +209,7 @@ def test_run_step_out_of_pages_is_atomic(setup):
                           max_slots=2, pages_per_seq=6, chunk_size=8,
                           enable_prefix_cache=False)
     sid = pr.prefill_seq(list(range(2, 26)))   # 24 tokens = 3 pages
+    steps_before = pr.n_ragged_steps
     free_before = pr.pm.num_free_pages
     len_before = pr.seq_len(sid)
     with pytest.raises(OutOfPages):
@@ -217,7 +217,7 @@ def test_run_step_out_of_pages_is_atomic(setup):
         pr.run_step([(sid, list(range(2, 19)), "prefill")])
     assert pr.pm.num_free_pages == free_before
     assert pr.seq_len(sid) == len_before
-    assert pr.n_ragged_steps == 0
+    assert pr.n_ragged_steps == steps_before
 
 
 def test_engine_one_kernel_call_per_step():

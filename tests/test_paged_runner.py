@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.core.paged_cache import OutOfPages
 from repro.core.paged_runner import PagedModelRunner, paged_supported
 from repro.models import model
 from repro.models.pdef import init_params
@@ -71,13 +72,22 @@ def _runner(rng_key, **kw):
     return PagedModelRunner(cfg, params, **kw)
 
 
+def _page_state(pr):
+    """``pm.stats()`` less ``live_token_steps``: that key is cumulative
+    telemetry every ragged step adds to, while the rest is page state a
+    free must restore exactly."""
+    st = pr.pm.stats()
+    del st["live_token_steps"]
+    return st
+
+
 def test_rewind_across_page_boundary(rng_key):
     """Rewinding k tokens that straddle a page boundary frees exactly
     the drained trailing page, and re-decoding the same tokens at the
     same positions reproduces the original logits bit-for-bit (the
     rejected K/V really is gone, not shadowing the rewritten one)."""
     pr = _runner(rng_key)
-    base = pr.pm.stats()
+    base = _page_state(pr)
     sid = pr.prefill_seq(list(range(1, 12)))           # 11 tokens, 3 pages
     first = {}
     for i, t in enumerate([20, 21, 22]):               # 12..14: page 4 opens
@@ -92,7 +102,7 @@ def test_rewind_across_page_boundary(rng_key):
         again = pr.decode({sid: t})[sid]
         assert np.array_equal(first[i], again), i
     pr.free(sid)
-    assert pr.pm.stats() == base
+    assert _page_state(pr) == base
 
 
 def test_rewind_cow_forked_tail(rng_key):
@@ -255,7 +265,7 @@ def test_int8_preempt_resume(rng_key):
     quantized stream matches straight-through int8 AND stays within
     quantization noise of the f32 oracle."""
     pr = _runner(rng_key, kv_dtype="int8")
-    base = pr.pm.stats()
+    base = _page_state(pr)
     prompt = list(range(2, 12))
     sid = pr.prefill_seq(prompt)
     kept = []
@@ -263,7 +273,7 @@ def test_int8_preempt_resume(rng_key):
         pr.decode({sid: t})
         kept.append(t)
     pr.free(sid)                                       # preemption
-    assert pr.pm.stats() == base
+    assert _page_state(pr) == base
     rsid = pr.prefill_seq(prompt + kept)               # resume
     resumed = pr.decode({rsid: 74})[rsid]
     straight = _runner(rng_key, kv_dtype="int8")
@@ -278,12 +288,40 @@ def test_int8_preempt_resume(rng_key):
     _close_and_same_argmax(resumed, f32.decode({fs: 74})[fs])
 
 
+def test_int8_pool_holds_more_sequences_under_one_byte_budget():
+    """Under one byte budget an int8 pool (values plus a bf16 scale per
+    token and kv head) keeps at least 1.8x the resident sequences of a
+    bf16 pool: each runner gets ``budget // page_bytes`` pages, and
+    sequences are admitted until ``OutOfPages``."""
+    cfg = get_config("llama-3.1-8b", reduced=True)
+    params = init_params(model.params_def(cfg), jax.random.PRNGKey(0))
+    page_size, prompt_len = 8, 16                      # 2 pages a sequence
+
+    def mk(kv_dtype, num_pages):
+        return PagedModelRunner(
+            cfg, params, num_pages=num_pages, page_size=page_size,
+            max_slots=256, pages_per_seq=2, enable_prefix_cache=False,
+            chunk_size=prompt_len, kv_dtype=kv_dtype)
+
+    budget = 16 * mk("f32", 1).page_bytes              # 16 bf16 pages
+    counts = {}
+    for kv_dtype in ("f32", "int8"):
+        runner = mk(kv_dtype, budget // mk(kv_dtype, 1).page_bytes)
+        counts[kv_dtype] = 0
+        with pytest.raises(OutOfPages):
+            while True:
+                runner.prefill_seq(list(range(1, prompt_len + 1)))
+                counts[kv_dtype] += 1
+    assert counts["f32"] == 8, counts
+    assert counts["int8"] >= 1.8 * counts["f32"], counts
+
+
 def test_rewind_then_preempt_then_resume(rng_key):
     """Round trip: speculate, reject (rewind), preempt (free without
     publish), then resume by re-prefilling prompt+kept tokens — the
     resumed stream continues exactly where the rewound one left off."""
     pr = _runner(rng_key)
-    base = pr.pm.stats()
+    base = _page_state(pr)
     prompt = list(range(2, 12))
     sid = pr.prefill_seq(prompt)
     kept = []
@@ -294,7 +332,7 @@ def test_rewind_then_preempt_then_resume(rng_key):
     pr.decode({sid: 73})
     pr.rewind_tokens(sid, 2)                           # ...and rejected
     pr.free(sid)                                       # preemption
-    assert pr.pm.stats() == base                       # fully returned
+    assert _page_state(pr) == base                     # fully returned
     rsid = pr.prefill_seq(prompt + kept)               # resume
     resumed = pr.decode({rsid: 74})[rsid]
     ref = _runner(rng_key)
@@ -304,4 +342,4 @@ def test_rewind_then_preempt_then_resume(rng_key):
     straight = ref.decode({ref_sid: 74})[ref_sid]
     assert np.allclose(resumed, straight, atol=1e-5)
     pr.free(rsid)
-    assert pr.pm.stats() == base
+    assert _page_state(pr) == base

@@ -239,7 +239,6 @@ def test_pipeline_stats_and_warmup(params):
         assert e["exec_steps"] > 0
         assert st["runner"]["attn_kernel_calls"] == e["exec_steps"]
         assert st["runner"]["host_logit_rows"] == 0
-        assert isinstance(e["dispatch_gap_ms"], float)
         assert isinstance(e["host_ms_per_step"], float)
     finally:
         eng.shutdown()

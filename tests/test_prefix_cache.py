@@ -362,13 +362,16 @@ def test_paged_engine_backend_interface():
     cfg = get_config("llama-3.1-8b", reduced=True)
     be = PagedEngineBackend(cfg, max_slots=2, max_context=64, page_size=8,
                             seed=0)
-    logits = be.prefill(0, list(range(2, 20)))
+    prompt = list(range(2, 20))
+    assert be.begin_prefill(0, prompt) == 0     # cold: nothing adopted
+    logits = be.run_step([(0, prompt, "prefill")])[0]
     assert logits.ndim == 1 and np.isfinite(logits).all()
-    out = be.decode({0: 5}, {0: 18})
+    out = be.run_step([(0, [5], "decode")])
     assert np.isfinite(out[0]).all()
     be.release(0)                               # publishes into the cache
     assert be.prefix_cache.cached_pages > 0
     # the slot is reusable and the next prefill hits the cache
-    be.prefill(0, list(range(2, 20)))
-    assert be.last_prefill_info["prefix_cached_tokens"] >= 8
+    cached = be.begin_prefill(0, prompt)
+    assert be.last_prefill_info["prefix_cached_tokens"] == cached >= 8
+    be.run_step([(0, prompt[cached:], "prefill")])
     be.release(0, publish=False)
